@@ -174,8 +174,15 @@ def json_summary() -> Dict:
     """CI artifact entrypoint. The sharded profile needs forced host
     devices, which must be set before jax initializes — when this process
     is too late for that (run.py imported other suites first), re-exec in a
-    clean subprocess and collect its JSON."""
+    clean subprocess and collect its JSON. Forced devices are CPU devices,
+    and a child could not open a chip this process already holds, so off
+    the CPU this refuses instead of re-executing."""
     import jax
+    if jax.default_backend() != "cpu":
+        raise RuntimeError(
+            "fleet_scale runs its fleet on forced CPU host devices; this "
+            f"process already holds the {jax.default_backend()!r} backend, "
+            "so a child cannot take over. Run it under JAX_PLATFORMS=cpu.")
     if jax.device_count() >= FORCED_DEVICES:
         return run(quiet=True)
     with tempfile.NamedTemporaryFile(suffix=".json", delete=False) as f:

@@ -47,6 +47,8 @@ def main() -> None:
                          "directory (CI benchmark-artifact mode)")
     args = ap.parse_args()
 
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     from benchmarks import (chunked_prefill, engine_week, fleet_engine,
                             fleet_scale, fleet_workers, kernels_bench,
                             operating_modes, paged_engine, qos_fleet,

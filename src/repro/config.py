@@ -2,8 +2,9 @@
 
 Every assigned architecture is expressed as a `ModelConfig`; the four assigned
 input shapes are `ShapeConfig`s. `RuntimeConfig` carries implementation
-switches (pallas on/off, remat policy, quantization format) that the perf
-hillclimb iterates on without touching model definitions.
+switches (remat policy, quantization format, ...) that the perf hillclimb
+iterates on without touching model definitions; whether kernels run as
+compiled Pallas or as the XLA reference follows the backend.
 """
 from __future__ import annotations
 
@@ -191,10 +192,20 @@ def applicable_shapes(model: ModelConfig):
 # ---------------------------------------------------------------------------
 
 
+def platform_kernels() -> bool:
+    """The platform picks the kernel path: compiled Pallas kernels on a TPU
+    backend, the XLA reference everywhere else (Pallas has no compiled CPU
+    lowering, and interpret mode is a test tool, not a serving path)."""
+    import jax
+    return jax.default_backend() == "tpu"
+
+
 @dataclasses.dataclass(frozen=True)
 class RuntimeConfig:
-    use_pallas: bool = False            # Pallas kernels (TPU) vs XLA reference paths
-    interpret: bool = True              # Pallas interpret mode (CPU container)
+    # Pallas kernels vs XLA reference paths; resolved from the backend at
+    # construction unless a caller (a kernel test, a reference run) says
+    use_pallas: bool = dataclasses.field(default_factory=platform_kernels)
+    interpret: bool = False             # Pallas interpret mode (CPU kernel tests)
     quant_format: str = "bf16"          # bf16 | q8 | q4 — serving weight format
     kv_cache_dtype: str = "bf16"        # bf16 | int8
     remat_policy: str = "full"          # full | save_dots | none

@@ -91,7 +91,7 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
 
 
 def flash_attention_bnh(q, k, v, *, causal=True, window=0, cap=0.0,
-                        q_offset=0, bq=128, bk=256, interpret=True):
+                        q_offset=0, bq=128, bk=256, interpret=False):
     """q: (B, N, Sq, H); k/v: (B, K, Skv, H) -> (B, N, Sq, H)."""
     B, N, Sq, H = q.shape
     K, Skv = k.shape[1], k.shape[2]

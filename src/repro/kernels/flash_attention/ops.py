@@ -11,7 +11,7 @@ from repro.kernels.flash_attention.flash_attention import flash_attention_bnh
 @functools.partial(jax.jit, static_argnames=("causal", "window", "cap",
                                              "q_offset", "interpret"))
 def flash_attention(q, k, v, *, causal=True, window=0, cap=0.0, q_offset=0,
-                    interpret=True):
+                    interpret):
     """q: (B, Sq, N, H); k/v: (B, Skv, K, H) -> (B, Sq, N, H)."""
     qt = q.swapaxes(1, 2)
     kt = k.swapaxes(1, 2)

@@ -5,12 +5,15 @@ pool: `block_tables` maps (sequence, logical block) -> physical block id, and
 the kernel walks a sequence's chain without ever materializing the gathered
 (B, S, K, H) view the XLA fallback builds.
 
-Grid: (batch, kv_head, max_blocks) — the block dimension is innermost and
+Grid: (batch, max_blocks) — the block dimension is innermost and
 sequential, carrying online-softmax state (m, l, acc) in VMEM scratch exactly
-like the flash-attention kernel. The block table and per-row lengths ride in
-as scalar-prefetch operands (`pltpu.PrefetchScalarGridSpec`), so the KV index
-maps can resolve `bt[b, j]` before the DMA for step j issues — the physical
-block fetch is data-dependent but still pipelined.
+like the flash-attention kernel. Each step DMAs one whole physical block
+(bs, K, H) and loops the K kv heads inside the kernel: a block spec whose
+last two dims are the array's own (K, H) is what the TPU lowering accepts,
+where a per-head (bs, 1, H) stripe is refused. The block table and per-row
+lengths ride in as scalar-prefetch operands (`pltpu.PrefetchScalarGridSpec`),
+so the KV index maps can resolve `bt[b, j]` before the DMA for step j issues
+— the physical block fetch is data-dependent but still pipelined.
 
 int8 pools (fused dequant): with `k_scale`/`v_scale` stripes the pool leaves
 are int8 and the per-(position, head) fp32 scales ride in as two extra
@@ -19,23 +22,23 @@ DMA (`k_int8 * scale`), so HBM traffic stays int8 — the bandwidth the block
 pool saved is the bandwidth the decode step saves.
 
 Split-K (flash-decode): `num_splits > 1` partitions the block chain over an
-extra grid axis — grid (batch, kv_head, split, blocks_per_split). Each split
+extra grid axis — grid (batch, split, blocks_per_split). Each split
 accumulates its own online-softmax partial and flushes (m, l, acc) into
 per-split VMEM scratch; the last split combines all partials with the usual
 max-rebased merge. For long chains this bounds the sequential chain walk per
 state vector — the lowering a real flash-decode pass parallelizes over
 megacore/vector units.
 
-GQA stays no-copy: q arrives as (B, K, G, H) and each kv head's program reads
-only its own (bs, H) stripes from the pool. Blocks past a row's length are
+GQA stays no-copy: q arrives as (B, K, G, H) and kv head h's G query heads
+read only the block's (bs, H) stripe of head h. Blocks past a row's length are
 skipped with `pl.when` (their DMA still targets a valid pool slot — dead rows
 point at the reserved scratch block 0), so a mostly-empty cache costs only its
 occupied blocks.
 
-VMEM per step (bs=16..128, H<=256): q G x H bf16 + k/v bs x H (bf16 or int8
-+ 2 x bs fp32 scales) + acc G x H f32 + m/l 2 x G x 128 f32 — plus, under
-split-K, S x (G x 128 + G x 128 + G x H) f32 partials — well under the
-budget for any real G.
+VMEM per step (bs=16..128, H<=256): q K x G x H bf16 + k/v bs x K x H (bf16
+or int8 + 2 x bs x K fp32 scales) + acc K x G x H f32 + m/l 2 x K x G x 128
+f32 — plus, under split-K, S x K x (2 x G x 128 + G x H) f32 partials — well
+under the budget for any real K x G.
 """
 from __future__ import annotations
 
@@ -62,14 +65,15 @@ def _kernel(bt_ref, len_ref, q_ref, k_ref, v_ref, *rest, bs: int, nbs: int,
     ms_ref = ls_ref = accs_ref = None
     if splits > 1:
         ms_ref, ls_ref, accs_ref = refs[4:]
+    K = q_ref.shape[1]
 
     b = pl.program_id(0)
     if splits > 1:
-        s_id = pl.program_id(2)
-        j = pl.program_id(3)
+        s_id = pl.program_id(1)
+        j = pl.program_id(2)
     else:
         s_id = 0
-        j = pl.program_id(2)
+        j = pl.program_id(1)
 
     @pl.when(j == 0)
     def _init():
@@ -81,41 +85,44 @@ def _kernel(bt_ref, len_ref, q_ref, k_ref, v_ref, *rest, bs: int, nbs: int,
     start = (s_id * nbs + j) * bs          # global position of this block
 
     def _compute():
-        q = q_ref[0, 0].astype(jnp.float32) * scale          # (G, H)
-        k = k_ref[0, :, 0].astype(jnp.float32)               # (bs, H)
-        v = v_ref[0, :, 0].astype(jnp.float32)
-        if quantized:
-            # fused dequant: int8 stripes just DMA'd, scales broadcast per
-            # position — the gathered bf16 view never exists anywhere
-            k = k * ks_ref[0, :, 0][:, None]
-            v = v * vs_ref[0, :, 0][:, None]
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)  # (G, bs)
-        if cap > 0.0:
-            s = jnp.tanh(s / cap) * cap
-        G = s.shape[0]
-        pos = start + jax.lax.broadcasted_iota(jnp.int32, (G, bs), 1)
-        ok = pos < length
-        if window > 0:
-            ok &= pos > length - 1 - window
-        s = jnp.where(ok, s, NEG_INF)
-        m_prev = m_ref[:, :1]                                # (G, 1)
-        m_cur = jnp.max(s, axis=1, keepdims=True)
-        m_new = jnp.maximum(m_prev, m_cur)
-        p = jnp.exp(s - m_new)
-        alpha = jnp.exp(m_prev - m_new)
-        l_ref[:, :1] = l_ref[:, :1] * alpha + p.sum(axis=1, keepdims=True)
-        acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
-        m_ref[:, :1] = m_new
+        for h in range(K):                  # kv heads of the resident block
+            q = q_ref[0, h].astype(jnp.float32) * scale      # (G, H)
+            k = k_ref[0, :, h, :].astype(jnp.float32)        # (bs, H)
+            v = v_ref[0, :, h, :].astype(jnp.float32)
+            if quantized:
+                # fused dequant: int8 stripes just DMA'd, scales broadcast
+                # per position — the gathered bf16 view never exists
+                k = k * ks_ref[0, :, h:h + 1]
+                v = v * vs_ref[0, :, h:h + 1]
+            s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                    preferred_element_type=jnp.float32)
+            if cap > 0.0:
+                s = jnp.tanh(s / cap) * cap
+            G = s.shape[0]
+            pos = start + jax.lax.broadcasted_iota(jnp.int32, (G, bs), 1)
+            ok = pos < length
+            if window > 0:
+                ok &= pos > length - 1 - window
+            s = jnp.where(ok, s, NEG_INF)
+            m_prev = m_ref[h, :, :1]                         # (G, 1)
+            m_cur = jnp.max(s, axis=1, keepdims=True)
+            m_new = jnp.maximum(m_prev, m_cur)
+            p = jnp.exp(s - m_new)
+            alpha = jnp.exp(m_prev - m_new)
+            l_ref[h, :, :1] = (l_ref[h, :, :1] * alpha
+                               + p.sum(axis=1, keepdims=True))
+            acc_ref[h] = acc_ref[h] * alpha + jax.lax.dot_general(
+                p, v, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            m_ref[h, :, :1] = m_new
 
     pl.when(start < length)(_compute)
 
     @pl.when(j == nbs - 1)
     def _flush():
         if splits == 1:
-            lsum = jnp.maximum(l_ref[:, :1], 1e-37)
-            o_ref[0, 0] = (acc_ref[...] / lsum).astype(o_ref.dtype)
+            lsum = jnp.maximum(l_ref[:, :, :1], 1e-37)
+            o_ref[0] = (acc_ref[...] / lsum).astype(o_ref.dtype)
         else:
             # park this split's partial online-softmax state; an untouched
             # split (chain shorter than its range) parks (NEG_INF, 0, 0),
@@ -126,13 +133,13 @@ def _kernel(bt_ref, len_ref, q_ref, k_ref, v_ref, *rest, bs: int, nbs: int,
 
             @pl.when(s_id == splits - 1)
             def _combine():
-                m_all = ms_ref[:, :, :1]                     # (S, G, 1)
-                m_tot = jnp.max(m_all, axis=0)               # (G, 1)
+                m_all = ms_ref[:, :, :, :1]                  # (S, K, G, 1)
+                m_tot = jnp.max(m_all, axis=0)               # (K, G, 1)
                 w = jnp.exp(m_all - m_tot[None])
-                l_tot = jnp.sum(ls_ref[:, :, :1] * w, axis=0)
-                acc_tot = jnp.sum(accs_ref[...] * w, axis=0)  # (G, H)
+                l_tot = jnp.sum(ls_ref[:, :, :, :1] * w, axis=0)
+                acc_tot = jnp.sum(accs_ref[...] * w, axis=0)  # (K, G, H)
                 lsum = jnp.maximum(l_tot, 1e-37)
-                o_ref[0, 0] = (acc_tot / lsum).astype(o_ref.dtype)
+                o_ref[0] = (acc_tot / lsum).astype(o_ref.dtype)
 
 
 def paged_attention_bkgh(q, k_pool, v_pool, block_tables, lengths, *,
@@ -153,50 +160,55 @@ def paged_attention_bkgh(q, k_pool, v_pool, block_tables, lengths, *,
                                window=int(window), quantized=quantized)
 
     if splits > 1:
-        grid = (B, K, splits, nbs)
+        grid = (B, splits, nbs)
 
-        def _chain(b, h, s, j, bt, ln):
+        def _block(b, s, j, bt, ln):
             # split s's j-th block; the ragged tail past nb-1 clamps to a
             # valid table slot (the kernel masks it via start >= length)
             return bt[b, jnp.minimum(s * nbs + j, nb - 1)]
-
-        q_map = lambda b, h, s, j, bt, ln: (b, h, 0, 0)
-        kv_map = lambda b, h, s, j, bt, ln: (_chain(b, h, s, j, bt, ln),
-                                             0, h, 0)
-        sc_map = lambda b, h, s, j, bt, ln: (_chain(b, h, s, j, bt, ln),
-                                             0, h)
     else:
-        grid = (B, K, nb)
-        q_map = lambda b, h, j, bt, ln: (b, h, 0, 0)
-        kv_map = lambda b, h, j, bt, ln: (bt[b, j], 0, h, 0)
-        sc_map = lambda b, h, j, bt, ln: (bt[b, j], 0, h)
+        grid = (B, nb)
 
+        def _block(b, j, bt, ln):
+            return bt[b, j]
+
+    def q_map(b, *rest):
+        return (b, 0, 0, 0)
+
+    def kv_map(*idx):
+        return (_block(*idx), 0, 0, 0)
+
+    def sc_map(*idx):
+        return (_block(*idx), 0, 0)
+
+    # whole (bs, K, H) pool blocks per step: the last two block dims equal
+    # the array's, which is what the TPU lowering requires of them
     in_specs = [
-        pl.BlockSpec((1, 1, G, H), q_map),
-        pl.BlockSpec((1, bs, 1, H), kv_map),
-        pl.BlockSpec((1, bs, 1, H), kv_map),
+        pl.BlockSpec((1, K, G, H), q_map),
+        pl.BlockSpec((1, bs, K, H), kv_map),
+        pl.BlockSpec((1, bs, K, H), kv_map),
     ]
     operands = [q, k_pool, v_pool]
     if quantized:
-        in_specs += [pl.BlockSpec((1, bs, 1), sc_map),
-                     pl.BlockSpec((1, bs, 1), sc_map)]
+        in_specs += [pl.BlockSpec((1, bs, K), sc_map),
+                     pl.BlockSpec((1, bs, K), sc_map)]
         operands += [k_scale.astype(jnp.float32), v_scale.astype(jnp.float32)]
     scratch = [
-        pltpu.VMEM((G, 128), jnp.float32),
-        pltpu.VMEM((G, 128), jnp.float32),
-        pltpu.VMEM((G, H), jnp.float32),
+        pltpu.VMEM((K, G, 128), jnp.float32),
+        pltpu.VMEM((K, G, 128), jnp.float32),
+        pltpu.VMEM((K, G, H), jnp.float32),
     ]
     if splits > 1:
         scratch += [
-            pltpu.VMEM((splits, G, 128), jnp.float32),
-            pltpu.VMEM((splits, G, 128), jnp.float32),
-            pltpu.VMEM((splits, G, H), jnp.float32),
+            pltpu.VMEM((splits, K, G, 128), jnp.float32),
+            pltpu.VMEM((splits, K, G, 128), jnp.float32),
+            pltpu.VMEM((splits, K, G, H), jnp.float32),
         ]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,                   # block_tables, lengths
         grid=grid,
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, G, H), q_map),
+        out_specs=pl.BlockSpec((1, K, G, H), q_map),
         scratch_shapes=scratch,
     )
     return pl.pallas_call(

@@ -19,7 +19,7 @@ def _pad_rows(x2d, multiple):
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def quant_matmul(x, w: QTensor, *, interpret: bool = True):
+def quant_matmul(x, w: QTensor, *, interpret: bool):
     """x: (..., K) @ QTensor (K, N) -> (..., N). Leading dims are flattened;
     rows padded to the sublane multiple the kernel tiles with."""
     *lead, K = x.shape

@@ -10,7 +10,8 @@ VMEM working set per grid step (defaults bm=128, bk=512, bn=256):
   q8:  x 128x512 bf16 (128 KiB) + w 512x256 int8 (128 KiB)
        + acc 128x256 f32 (128 KiB) + scale 1x256 f32 (1 KiB)   ~= 385 KiB
   q4:  bk=128 (= group size): x 32 KiB + w-packed 64x256 uint8 (16 KiB)
-       + scale/zero 2x1x256 f32 + acc 128 KiB                  ~= 178 KiB
+       + scale/zero 2x(K/128)x256 f32 (296 KiB at K=18944) + acc 128 KiB
+                                                               ~= 472 KiB
 Both fit VMEM (~128 MiB on v5e) with generous double-buffering headroom.
 MXU alignment: bn, bk multiples of 128; bm multiple of 8 (f32 sublane).
 """
@@ -53,7 +54,7 @@ def _fit(n: int, pref: int) -> int:
     return n
 
 
-def q8_matmul(x, wq, scale, *, bm=128, bk=512, bn=256, interpret=True):
+def q8_matmul(x, wq, scale, *, bm=128, bk=512, bn=256, interpret=False):
     """x: (M, K) bf16; wq: (K, N) int8; scale: (1, N) f32 -> (M, N) bf16."""
     M, K = x.shape
     K2, N = wq.shape
@@ -88,14 +89,16 @@ def _q4_kernel(x_ref, w_ref, s_ref, z_ref, o_ref, acc_ref, *, nk: int):
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     x = x_ref[...].astype(jnp.float32)               # (bm, bk)
-    packed = w_ref[...]                              # (bk/2, bn) uint8
+    # (bk/2, bn) uint8; the TPU lowering casts uint8 to int32, not to f32
+    packed = w_ref[...].astype(jnp.int32)
     lo = (packed & 0x0F).astype(jnp.float32)
     hi = (packed >> 4).astype(jnp.float32)
     bk2, bn = packed.shape
     # packing is (even_rows | odd_rows << 4): un-interleave
     q = jnp.stack([lo, hi], axis=1).reshape(bk2 * 2, bn)
-    s = s_ref[...].astype(jnp.float32)               # (1, bn): block = 1 group
-    z = z_ref[...].astype(jnp.float32)               # (1, bn)
+    k = pl.program_id(2)                             # block = 1 group
+    s = s_ref[pl.ds(k, 1), :].astype(jnp.float32)    # (1, bn)
+    z = z_ref[pl.ds(k, 1), :].astype(jnp.float32)    # (1, bn)
     # sum_k x_k*(q*s + z) = s * (x @ q) + (sum_k x_k) * z
     acc_ref[...] += s * jnp.dot(x, q, preferred_element_type=jnp.float32)
     acc_ref[...] += x.sum(axis=1, keepdims=True) * z
@@ -105,7 +108,7 @@ def _q4_kernel(x_ref, w_ref, s_ref, z_ref, o_ref, acc_ref, *, nk: int):
         o_ref[...] = acc_ref[...].astype(o_ref.dtype)
 
 
-def q4_matmul(x, wq, scale, zero, *, group=128, bm=128, bn=256, interpret=True):
+def q4_matmul(x, wq, scale, zero, *, group=128, bm=128, bn=256, interpret=False):
     """x: (M, K) bf16; wq: (K/2, N) uint8 packed; scale/zero: (K/g, N) f32."""
     M, K = x.shape
     N = wq.shape[1]
@@ -121,8 +124,11 @@ def q4_matmul(x, wq, scale, zero, *, group=128, bm=128, bn=256, interpret=True):
         in_specs=[
             pl.BlockSpec((bm, bk), lambda i, j, k: (i, k)),
             pl.BlockSpec((bk // 2, bn), lambda i, j, k: (k, j)),
-            pl.BlockSpec((1, bn), lambda i, j, k: (k, j)),
-            pl.BlockSpec((1, bn), lambda i, j, k: (k, j)),
+            # every group's scale/zero row of the column block stays
+            # resident across k: a one-row block of the (K/g, N) arrays
+            # is not a tile the TPU lowering accepts
+            pl.BlockSpec((nk, bn), lambda i, j, k: (0, j)),
+            pl.BlockSpec((nk, bn), lambda i, j, k: (0, j)),
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((M, N), x.dtype),

@@ -9,6 +9,6 @@ from repro.kernels.ssd.ssd import ssd_bshp
 
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
-def ssd(x, dt, A, Bm, Cm, *, chunk=128, interpret=True):
+def ssd(x, dt, A, Bm, Cm, *, chunk=128, interpret):
     y, fs = ssd_bshp(x, dt, A, Bm, Cm, chunk=chunk, interpret=interpret)
     return y.astype(x.dtype), fs

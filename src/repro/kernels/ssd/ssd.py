@@ -62,7 +62,7 @@ def _kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, fs_ref, state_ref, *,
         fs_ref[0, 0] = state_ref[...].astype(fs_ref.dtype)
 
 
-def ssd_bshp(x, dt, A, Bm, Cm, *, chunk=128, interpret=True):
+def ssd_bshp(x, dt, A, Bm, Cm, *, chunk=128, interpret=False):
     """x: (B,S,H,P); dt: (B,S,H) post-softplus; A: (H,) negative;
     Bm/Cm: (B,S,G,N). Returns (y (B,S,H,P) f32-accurate, final (B,H,P,N) f32)."""
     Bb, S, H, P = x.shape

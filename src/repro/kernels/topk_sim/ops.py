@@ -15,7 +15,7 @@ def _normalize(x):
 
 
 @functools.partial(jax.jit, static_argnames=("k", "interpret"))
-def topk_tools(tool_embeds, query_embeds, *, k: int, interpret: bool = True):
+def topk_tools(tool_embeds, query_embeds, *, k: int, interpret: bool):
     """tool_embeds: (N, d) pre-normalized; query_embeds: (m, d) raw.
     Returns (scores (k,), indices (k,))."""
     q = _normalize(query_embeds)

@@ -31,7 +31,7 @@ def _kernel(t_ref, q_ref, o_ref):
     o_ref[0, :] = jnp.max(sims, axis=1)
 
 
-def sim_scores(tools, queries, *, bt=1024, interpret=True):
+def sim_scores(tools, queries, *, bt=1024, interpret=False):
     """tools: (N, d) L2-normalized; queries: (m, d) L2-normalized
     -> scores (N,) = max over queries of cosine similarity."""
     N, d = tools.shape

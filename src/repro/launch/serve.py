@@ -1,8 +1,13 @@
-"""Serving launcher: the CarbonCall runtime on a REAL JAX model (reduced
-config, CPU) — tool selection, CI-driven operating modes, and live Q8/Q4
-hot-swap on the serving engine.
+"""Serving launcher: the CarbonCall runtime on a REAL JAX model — tool
+selection, CI-driven operating modes, and live Q8/Q4 hot-swap on the serving
+engine.
 
   PYTHONPATH=src python -m repro.launch.serve --queries 12 --minutes-per-query 30
+
+By default the model is the reduced config (d_model 64), which runs on a
+CPU. ``--full-width`` serves the architecture at its published widths
+(Qwen2-7B: 28 layers, d 3584, vocab 152064) with both variants built piece
+by piece from the seed (`quant.build_variants`) — for one TPU v5e.
 
 With ``--workers N`` the same query stream is served by N worker PROCESSES
 behind the engine control protocol (launch/workers.py): each worker builds
@@ -26,8 +31,9 @@ from repro.core import (CarbonGovernor, ORIN_MODES, ToolSelector,
                         forecast_trace)
 from repro.core.power import PowerModel
 from repro.data.workload import build_catalog, FunctionCallWorkload
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import get_model
-from repro.quant import quantize_tree
+from repro.quant import build_variants, quantize_tree
 from repro.serving import (EngineConfig, EngineStats, ServingEngine,
                            SessionRequest, WorkerSpec)
 from repro.sharding.param import init_params
@@ -50,9 +56,17 @@ def main():
     ap.add_argument("--workers", type=int, default=0,
                     help="serve through N worker processes behind the "
                          "control protocol (0 = in-process engine)")
+    ap.add_argument("--full-width", action="store_true",
+                    help="serve the architecture at its published widths "
+                         "(in-process; one TPU v5e for the paper's model)")
     args = ap.parse_args()
+    if args.full_width and args.workers:
+        ap.error("--full-width serves in-process; drop --workers")
+    enable_compile_cache()
 
-    cfg = reduce_config(get_arch(args.arch))
+    cfg = get_arch(args.arch)
+    if not args.full_width:
+        cfg = reduce_config(cfg)
     econfig = EngineConfig(max_batch=4, max_seq=128)
     workers = []
     client = None
@@ -68,11 +82,14 @@ def main():
         rcfg = RuntimeConfig()
         model = get_model(cfg)
         spec = model.param_spec()
-        params = init_params(spec, jax.random.PRNGKey(0))
-        variants = {
-            "q8": quantize_tree(params, spec, "q8"),
-            "q4": quantize_tree(params, spec, "q4"),
-        }
+        if args.full_width:
+            variants = build_variants(spec, jax.random.PRNGKey(0))
+        else:
+            params = init_params(spec, jax.random.PRNGKey(0))
+            variants = {
+                "q8": quantize_tree(params, spec, "q8"),
+                "q4": quantize_tree(params, spec, "q4"),
+            }
         engine = ServingEngine(cfg, variants["q8"], rcfg, config=econfig)
         engine.variant_name = "q8"
         client = engine.client()

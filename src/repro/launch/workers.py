@@ -256,8 +256,10 @@ def _worker_main(conn, spec_wire: Dict[str, Any]) -> None:
     """Worker process entry: stage the environment, build the actor, then
     serve the request/reply loop until shutdown or EOF. Runs in a SPAWNED
     interpreter — jax has not loaded yet, so the forced host device count
-    for sharded configs can still take effect."""
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
+    for sharded configs can still take effect. The platform is the one the
+    parent's environment names (JAX_PLATFORMS is inherited, not forced): a
+    worker that cannot open its backend — e.g. a chip another process
+    holds — fails its build, and the handshake carries the error back."""
     shards = int(dict(spec_wire.get("config") or {}).get("data_shards", 1))
     if shards > 1:
         flags = [f for f in os.environ.get("XLA_FLAGS", "").split()

@@ -11,12 +11,13 @@ with two small all-reduces (flash-decode pattern).
 """
 from __future__ import annotations
 
+import functools
 from typing import Tuple
 
 import jax
 import jax.numpy as jnp
 
-from repro.sharding.rules import constrain
+from repro.sharding.rules import batch_parallel, constrain
 
 NEG_INF = -1e30
 
@@ -184,10 +185,11 @@ def attention(q, k, v, rcfg, **kw):
     """Dispatch on RuntimeConfig. Pallas path lives in kernels/flash_attention."""
     if rcfg is not None and rcfg.use_pallas:
         from repro.kernels.flash_attention import ops as fa_ops
-        return fa_ops.flash_attention(
-            q, k, v, causal=kw.get("causal", True), window=kw.get("window", 0),
-            cap=kw.get("cap", 0.0), q_offset=kw.get("q_offset", 0),
-            interpret=rcfg.interpret)
+        return batch_parallel(functools.partial(
+            fa_ops.flash_attention, causal=kw.get("causal", True),
+            window=kw.get("window", 0), cap=kw.get("cap", 0.0),
+            q_offset=kw.get("q_offset", 0), interpret=rcfg.interpret),
+            (q, k, v))
     chunk = rcfg.attn_chunk if rcfg is not None else 512
     if q.shape[1] * k.shape[1] <= 512 * 512:
         return naive_attention(q, k, v, **kw)

@@ -5,6 +5,8 @@ from repro.quant.qtensor import (
     quantize_tree,
     dense,
     quant_spec,
+    build_variants,
 )
 
-__all__ = ["QTensor", "quantize", "dequantize", "quantize_tree", "dense", "quant_spec"]
+__all__ = ["QTensor", "quantize", "dequantize", "quantize_tree", "dense", "quant_spec",
+           "build_variants"]

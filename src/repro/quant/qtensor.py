@@ -12,12 +12,15 @@ fused Pallas dequant-matmul kernel (RuntimeConfig.use_pallas).
 from __future__ import annotations
 
 import dataclasses
+import functools
+import math
 from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
-from repro.sharding.param import ParamDef
+from repro.sharding.param import ParamDef, _init_leaf
+from repro.sharding.rules import batch_parallel
 
 Q4_GROUP = 128
 
@@ -130,9 +133,15 @@ def dense(x: jax.Array, w, rcfg=None, *, spec: Optional[str] = None):
     """x: (..., d_in) @ w: (..., d_in, d_out) with optional leading batch dims
     on w that broadcast/batch against x (used by stacked experts)."""
     if _is_qt(w):
-        if rcfg is not None and rcfg.use_pallas and w.q.ndim == 2:
+        if rcfg is not None and rcfg.use_pallas:
+            if w.q.ndim != 2:
+                raise NotImplementedError(
+                    f"quant_matmul kernel takes 2-D weights; got {w.fmt} "
+                    f"{w.shape} (batched experts have no kernel path)")
             from repro.kernels.quant_matmul import ops as qm_ops
-            return qm_ops.quant_matmul(x, w, interpret=rcfg.interpret)
+            return batch_parallel(
+                functools.partial(qm_ops.quant_matmul,
+                                  interpret=rcfg.interpret), (x,), (w,))
         if w.fmt == "q4" and w.q.ndim == 2:
             return _q4_matmul_xla(x, w)
         w = dequantize(w, x.dtype)
@@ -219,3 +228,102 @@ def quantize_tree(params, spec, fmt: str, group: int = Q4_GROUP):
     return jax.tree.map(
         go, qspec, params,
         is_leaf=lambda x: isinstance(x, (QTensor, ParamDef)))
+
+
+# ---------------------------------------------------------------------------
+# Building variants without the bf16 tree
+# ---------------------------------------------------------------------------
+
+# largest piece a leaf is drawn in: 128 MiB in bf16, twice that while it is
+# quantized in float32 — the builder's transient, whatever the model's size
+MAX_PIECE_ELEMS = 1 << 26
+
+
+def _pieces(d: ParamDef):
+    """Piece shape and start offsets leaf `d` is drawn in: a stacked leaf
+    (leading "layers" dim) one layer slice at a time, and the last (output)
+    dim split into the fewest equal parts that keep a piece within
+    MAX_PIECE_ELEMS. Quantization runs along the contraction dim per output
+    column, so a piece quantizes exactly as its span of the whole leaf."""
+    shape = list(d.shape)
+    stacked = bool(shape) and d.logical[0] == "layers"
+    piece = [1] + shape[1:] if stacked else list(shape)
+    parts = 1
+    if len(shape) > int(stacked):
+        n = shape[-1]
+        rest = math.prod(piece[:-1])
+        parts = next((p for p in range(1, n + 1)
+                      if n % p == 0 and rest * (n // p) <= MAX_PIECE_ELEMS),
+                     n)
+        piece[-1] = n // parts
+    starts = []
+    for layer in (range(shape[0]) if stacked else [0]):
+        for c in range(parts):
+            st = [0] * len(shape)
+            if stacked:
+                st[0] = layer
+            if len(shape) > int(stacked):
+                st[-1] = c * piece[-1]
+            starts.append(tuple(st))
+    return tuple(piece), starts
+
+
+_draw = jax.jit(_init_leaf, static_argnums=(0, 2))
+
+
+@functools.partial(jax.jit, donate_argnums=(0,))
+def _write(bufs, vals, start):
+    """Write each piece into its (donated, so updated in place) buffer."""
+    return [jax.lax.dynamic_update_slice(b, v.astype(b.dtype), start)
+            for b, v in zip(bufs, vals)]
+
+
+def build_variants(spec, key, fmts=("q8", "q4"), *, sharding=None):
+    """Seeded weights for `spec`, quantized to each format in `fmts`, built
+    piece by piece: no whole bf16 tree ever exists, so the peak transient is
+    one piece (see `_pieces`) rather than the model (15 GB in bf16 for a
+    7B-class model, which does not fit beside its variants on a 16 GB chip).
+
+    Returns {fmt: tree} shaped like `quant_spec(spec, fmt)`; "bf16" is the
+    unquantized tree the pieces were drawn from. Leaves no format quantizes
+    (norms, biases, the embedding table) are one array shared by every
+    variant. `sharding` is a replicated placement (e.g. over a data mesh)
+    every piece is drawn and stored with; None means the default device."""
+    leaves, treedef = jax.tree.flatten(spec,
+                                       is_leaf=lambda x: isinstance(x, ParamDef))
+    keys = jax.random.split(key, len(leaves))
+    qnodes = [treedef.flatten_up_to(quant_spec(spec, f)) for f in fmts]
+    out = {f: [] for f in fmts}
+    for i, d in enumerate(leaves):
+        nodes = [qn[i] for qn in qnodes]
+        raw = any(not _is_qt(n) for n in nodes)
+        quantized = [n for n in nodes if _is_qt(n)]
+        qfmts = tuple((n.fmt, n.group) for n in quantized)
+        defs = ([d] if raw else []) + [c for n in quantized for c in
+                                        (n.q, n.scale, n.zero) if c is not None]
+        bufs = [jnp.zeros(c.shape, c.jnp_dtype, device=sharding) for c in defs]
+        piece, starts = _pieces(d)
+        for j, start in enumerate(starts):
+            kj = jax.random.fold_in(keys[i], j)
+            if sharding is not None:    # draw where the buffers live
+                kj = jax.device_put(kj, sharding)
+            w = _draw(d, kj, piece)
+            # quantized op by op, exactly as `quantize_tree` does it: fused
+            # into one program, XLA may round a division differently
+            vals = [w] if raw else []
+            for fmt, g in qfmts:
+                t = quantize(w, fmt, g)
+                vals += [t.q, t.scale] + ([t.zero] if t.zero is not None
+                                          else [])
+            bufs = _write(bufs, vals, start)
+        pos = int(raw)
+        for f, n in zip(fmts, nodes):
+            if not _is_qt(n):
+                out[f].append(bufs[0])
+                continue
+            k = 3 if n.zero is not None else 2
+            q, scale, *zero = bufs[pos:pos + k]
+            pos += k
+            out[f].append(QTensor(q=q, scale=scale, zero=zero[0] if zero else None,
+                                  fmt=n.fmt, group=n.group))
+    return {f: jax.tree.unflatten(treedef, v) for f, v in out.items()}

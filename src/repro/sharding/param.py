@@ -52,19 +52,22 @@ def _is_def(x):
     return isinstance(x, ParamDef)
 
 
-def _init_leaf(d: ParamDef, key):
+def _init_leaf(d: ParamDef, key, shape=None):
+    """Draw leaf `d` — or, with `shape`, one piece of it: the init rule and
+    fan-in are the whole leaf's, only the drawn block is smaller."""
+    shape = d.shape if shape is None else tuple(shape)
     if d.init == "zeros":
-        return jnp.zeros(d.shape, d.jnp_dtype)
+        return jnp.zeros(shape, d.jnp_dtype)
     if d.init == "ones":
-        return jnp.ones(d.shape, d.jnp_dtype)
+        return jnp.ones(shape, d.jnp_dtype)
     if d.init == "fan_in":
         # last-but-one dim is fan-in for (..., d_in, d_out) kernels
         fan_in = d.shape[-2] if len(d.shape) >= 2 else max(d.shape[-1], 1)
         std = d.scale / math.sqrt(fan_in)
-        return (jax.random.normal(key, d.shape, jnp.float32) * std).astype(d.jnp_dtype)
+        return (jax.random.normal(key, shape, jnp.float32) * std).astype(d.jnp_dtype)
     if d.init in ("normal", "embed", "small"):
         std = {"normal": 0.02, "embed": 1.0, "small": 1e-3}[d.init] * d.scale
-        return (jax.random.normal(key, d.shape, jnp.float32) * std).astype(d.jnp_dtype)
+        return (jax.random.normal(key, shape, jnp.float32) * std).astype(d.jnp_dtype)
     raise ValueError(d.init)
 
 

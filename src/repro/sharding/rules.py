@@ -182,6 +182,28 @@ def current_mesh() -> Optional[Mesh]:
     return _ACTIVE_MESH[-1] if _ACTIVE_MESH else None
 
 
+def batch_parallel(fn, batched, replicated=()):
+    """Call a Pallas kernel wrapper as ``fn(*batched, *replicated)``, once per
+    batch shard of the active mesh.
+
+    The compiler cannot partition a Mosaic kernel, so under a data-parallel
+    mesh each device runs the kernel on its own rows — the leading dim of
+    every `batched` operand — against whole copies of the `replicated` ones
+    (weights). The result is batch-sharded the same way. With no mesh, or
+    one whose batch axes all have size 1, it is a plain call."""
+    mesh = current_mesh()
+    axes = () if mesh is None else tuple(
+        a for a in current_rules()["act_batch"]
+        if a in mesh.shape and mesh.shape[a] > 1)
+    if not axes:
+        return fn(*batched, *replicated)
+    bspec = PartitionSpec(axes if len(axes) > 1 else axes[0])
+    return jax.shard_map(
+        fn, mesh=mesh,
+        in_specs=(bspec,) * len(batched) + (PartitionSpec(),) * len(replicated),
+        out_specs=bspec, check_vma=False)(*batched, *replicated)
+
+
 def constrain(x, logical: Sequence[Optional[str]], rules: Optional[Dict] = None):
     """with_sharding_constraint by logical axes; no-op outside activate_mesh."""
     mesh = current_mesh()

@@ -6,3 +6,8 @@ import os
 flags = os.environ.get("XLA_FLAGS", "")
 os.environ["XLA_FLAGS"] = " ".join(
     f for f in flags.split() if "force_host_platform_device_count" not in f)
+
+# The suite runs on the CPU: kernels in interpret mode where a test asks for
+# them, the XLA reference otherwise. Worker and forced-device subprocesses
+# inherit this environment, so they stay on the CPU too.
+os.environ.setdefault("JAX_PLATFORMS", "cpu")

@@ -77,3 +77,28 @@ def test_bytes_reduction():
     b4 = nbytes(quantize_tree(params, spec, "q4"))
     assert b8 < 0.75 * b16                        # embed stays bf16
     assert b4 < b8
+
+
+@pytest.mark.parametrize("max_piece", [1 << 26, 2048])
+def test_build_variants_matches_quantize_tree(max_piece, monkeypatch):
+    """The piece-by-piece builder never holds a bf16 tree, but the bf16
+    pieces it draws, assembled, quantize leaf for leaf to its Q8 and Q4
+    trees. A small piece limit also splits the 2-D leaves (LM head,
+    embedding) along their output dim, as the 152064-vocab head is."""
+    from repro.common.registry import get_arch
+    from repro.configs.reduced import reduce_config
+    from repro.quant import build_variants, qtensor
+    monkeypatch.setattr(qtensor, "MAX_PIECE_ELEMS", max_piece)
+    spec = get_model(reduce_config(get_arch("carboncall-qwen2-7b"))).param_spec()
+    built = build_variants(spec, jax.random.PRNGKey(3), ("bf16", "q8", "q4"))
+    assert jax.tree.structure(built["bf16"]) == jax.tree.structure(
+        init_params(spec, jax.random.PRNGKey(0)))
+    for fmt in ("q8", "q4"):
+        want = quantize_tree(built["bf16"], spec, fmt)
+        assert jax.tree.structure(want) == jax.tree.structure(built[fmt])
+        for a, b in zip(jax.tree.leaves(want), jax.tree.leaves(built[fmt])):
+            assert a.shape == b.shape and a.dtype == b.dtype
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    # leaves no format quantizes are one array shared by the variants
+    assert built["q8"]["embed"] is built["q4"]["embed"]
+    assert built["q8"]["embed"] is built["bf16"]["embed"]
