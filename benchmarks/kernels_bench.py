@@ -111,7 +111,8 @@ def run():
         wbytes = t.nbytes()
         bf16_bytes = K * N * 2
         timed(f"kernels/quant_matmul/{fmt}_{M}x{K}x{N}",
-              lambda: jax.block_until_ready(qm_ops.quant_matmul(x, t)),
+              lambda: jax.block_until_ready(
+                  qm_ops.quant_matmul(x, t, interpret=True)),
               derived_fn=lambda _: (
                   f"hbm_bytes={wbytes} vs bf16={bf16_bytes} "
                   f"speedup_mem_bound={bf16_bytes/wbytes:.2f}x "
@@ -123,13 +124,14 @@ def run():
     v = jax.random.normal(key, (B, S, Kh, H), jnp.bfloat16)
     flops = 4 * B * S * (S / 2) * Nh * H
     timed(f"kernels/flash_attention/causal_{S}",
-          lambda: jax.block_until_ready(fa_ops.flash_attention(q, k, v)),
+          lambda: jax.block_until_ready(
+              fa_ops.flash_attention(q, k, v, interpret=True)),
           derived_fn=lambda _: (
               f"flops={flops:.2e} v5e_t_us={flops/TPU_V5E.peak_flops*1e6:.2f} "
               "o_s_memory=no_s2_materialization"))
     timed(f"kernels/flash_attention/window_{S}w128",
           lambda: jax.block_until_ready(
-              fa_ops.flash_attention(q, k, v, window=128)),
+              fa_ops.flash_attention(q, k, v, window=128, interpret=True)),
           derived_fn=lambda _: "block_skip=sub_quadratic_local_layers")
 
     Bs, Ss, Hh, P, G, Nst = 1, 512, 4, 64, 1, 64
@@ -140,7 +142,8 @@ def run():
     Cm = jax.random.normal(key, (Bs, Ss, G, Nst)) * 0.3
     ssd_flops = Bs * Ss * Hh * (2 * 128 * Nst + 2 * 128 * P + 4 * Nst * P)
     timed(f"kernels/ssd/chunked_{Ss}",
-          lambda: jax.block_until_ready(ssd_ops.ssd(xs, dt, A, Bm, Cm)),
+          lambda: jax.block_until_ready(
+              ssd_ops.ssd(xs, dt, A, Bm, Cm, interpret=True)),
           derived_fn=lambda _: (
               f"flops={ssd_flops:.2e} "
               f"v5e_t_us={ssd_flops/TPU_V5E.peak_flops*1e6:.3f}"))
@@ -152,7 +155,8 @@ def run():
     qs = jax.random.normal(key, (4, 128))
     sim_bytes = 2048 * 128 * 4
     timed("kernels/topk_sim/2048x128",
-          lambda: jax.block_until_ready(tk_ops.topk_tools(tools, qs, k=8)),
+          lambda: jax.block_until_ready(
+              tk_ops.topk_tools(tools, qs, k=8, interpret=True)),
           derived_fn=lambda _: (
               f"hbm_bytes={sim_bytes} (m x N sims never materialized) "
               f"v5e_t_us={sim_bytes/TPU_V5E.hbm_bandwidth*1e6:.3f}"))

@@ -10,12 +10,14 @@ One chip: the paper's Qwen2-7B (`carboncall-qwen2-7b`: 28 layers, d 3584,
 Its Q8 and Q4 variants are built piece by piece (`quant.build_variants`) and
 both stay resident. A `ServingEngine` with the default paged KV layout
 serves temperature-0 requests whose 128-token prompts share a 96-token tool
-prefix, through `EngineClient.submit`/`settle`: a first wave, a second wave
-that hits the cached prefix, one live `swap_params` Q8 -> Q4 (the CarbonCall
-variant switch), and a wave on Q4. Correctness: the engine's prefill logits
-(kept with its prefix-cache entries) and a few teacher-forced decode steps'
-logits through the compiled kernels are compared with the same model on the
-XLA reference path (`use_pallas=False`) on the same chip.
+prefix, through `EngineClient.submit`/`settle`: a full batch of 8, a second
+wave that hits the cached prefix, one live `swap_params` Q8 -> Q4 (the
+CarbonCall variant switch), and a wave on Q4. Correctness, for every request:
+the engine's own prefill logits (kept with its prefix-cache entries) and the
+rows of its first batched paged decode steps are compared with the same model
+on the XLA reference path (`use_pallas=False`) on the same chip, fed the
+engine's tokens. Planted faults (a misread quant scale, a dropped KV head, a
+masked KV block) must read above the tolerance, or the check fails too.
 
 `--chips 4`: only the sharded path — a dense-layout engine over a 4-device
 data mesh at published widths (Q8), against the same requests on a
@@ -129,42 +131,104 @@ def serve(client, prompts):
     return outs
 
 
-def probe_logits(model, params, rcfg, prompt, forced, *, max_seq, block_size):
-    """The model's logits for one row: the prefill's last position, then
-    `forced` fed one token per paged decode step (teacher-forced, so two
-    paths compared see the same inputs). Returns (1 + len(forced), V) f32."""
+class Reference:
+    """The model on a given runtime path at batch 1: prefill a prompt into a
+    private block pool, then feed teacher-forced tokens one paged decode step
+    at a time. Compiled once per weight-tree structure."""
+
+    def __init__(self, model, rcfg, *, max_seq, block_size):
+        import jax
+        from repro.sharding.param import init_params
+        self.nb = nb = -(-max_seq // block_size)
+        self.block_size = block_size
+        self.cache0 = init_params(model.cache_spec(rcfg, 1, max_seq),
+                                  jax.random.PRNGKey(0))
+        # blocks 1..nb hold the row; block 0 is never written, so it reads
+        # as zeros (the masked-block control points a table entry at it)
+        self.pool0 = init_params(model.paged_cache_spec(rcfg, nb + 1, block_size),
+                                 jax.random.PRNGKey(0))
+
+        def prefill(p, cache, pool, toks):
+            logits, cache, _ = model.prefill(p, cache, {"tokens": toks}, rcfg)
+            pool = {k: pool[k].at[:, 1:].set(v[:, 0].reshape(
+                        v.shape[0], nb, block_size, *v.shape[3:]))
+                    for k, v in cache.items()}
+            return logits, pool
+
+        self.prefill = jax.jit(prefill)
+        self.decode = jax.jit(functools.partial(
+            model.decode_step_paged, rcfg=rcfg, seq_cap=max_seq))
+
+    def logits(self, params, prompt, forced, *, fault=None):
+        """(1 + len(forced), V) f32: the prefill's last position, then one
+        row per forced token. `fault` plants one error (see FAULTS)."""
+        import jax.numpy as jnp
+        import numpy as np
+        logits, pool = self.prefill(params, self.cache0, self.pool0,
+                                    jnp.asarray([prompt], jnp.int32))
+        tables = np.arange(1, self.nb + 1, dtype=np.int32)[None]
+        if fault == "dropped kv head":
+            pool = {k: v.at[..., -1, :].set(0) for k, v in pool.items()}
+        elif fault == "masked last prompt block":
+            tables[0, (len(prompt) - 1) // self.block_size] = 0
+        rows = [logits[0]]
+        for t, tok in enumerate(forced):
+            logits, pool = self.decode(params, pool, jnp.asarray([[tok]], jnp.int32),
+                                       jnp.asarray([len(prompt) + t], jnp.int32),
+                                       jnp.asarray(tables))
+            rows.append(logits[0])
+        return np.asarray(jnp.stack(rows), np.float32)
+
+
+# Planted faults, each the signature of a kernel bug, that the logit check
+# must read above LOGIT_TOL (so it can tell a fault from bf16 drift).
+FAULTS = ("quant scale of the next group or column", "dropped kv head",
+          "masked last prompt block")
+
+
+def misread_scales(params):
+    """Every quantized weight dequantized with its neighbour's scale: the
+    next group's (Q4) or the next column's (Q8, and Q4 with one group)."""
     import jax
     import jax.numpy as jnp
+    from repro.quant import QTensor
+
+    def shift(t):
+        if not isinstance(t, QTensor):
+            return t
+        axis = -2 if t.scale.shape[-2] > 1 else -1
+        return dataclasses.replace(t, scale=jnp.roll(t.scale, 1, axis=axis))
+    return jax.tree.map(shift, params, is_leaf=lambda x: isinstance(x, QTensor))
+
+
+def record_decode_logits(engine, steps: int):
+    """Keep the logits the engine's own decode program produces: for each
+    request (keyed by prompt), the rows of its first `steps` decode steps,
+    taken from the batched step it ran in. Also counts live slots."""
     import numpy as np
-    from repro.sharding.param import init_params
+    rows, live = {}, []
+    # the engine fetches its jitted decode step through `_decode_fn` before
+    # every step; wrapping it sees each step's logits and slots unchanged
+    build = engine._decode_fn
 
-    nb = -(-max_seq // block_size)
-    cache0 = init_params(model.cache_spec(rcfg, 1, max_seq),
-                         jax.random.PRNGKey(0))
-    pool0 = init_params(model.paged_cache_spec(rcfg, nb + 1, block_size),
-                        jax.random.PRNGKey(0))
+    def decode_fn(variant=None):
+        fn = build(variant)
 
-    @jax.jit
-    def prefill(p, cache, pool, toks):
-        logits, cache, _ = model.prefill(p, cache, {"tokens": toks}, rcfg)
-        # the dense stripe into blocks 1..nb (block 0 is the scratch block)
-        pool = {k: pool[k].at[:, 1:].set(v[:, 0].reshape(
-                    v.shape[0], nb, block_size, *v.shape[3:]))
-                for k, v in cache.items()}
-        return logits, pool
+        def step(*args):
+            logits, state = fn(*args)
+            reqs = [(i, r) for i, r in enumerate(engine.slots) if r is not None]
+            live.append(len(reqs))
+            want = [(i, r) for i, r in reqs
+                    if len(rows.get(tuple(r.prompt), ())) < steps]
+            if want:
+                host = np.asarray(logits, np.float32)
+                for i, r in want:
+                    rows.setdefault(tuple(r.prompt), []).append(host[i])
+            return logits, state
+        return step
 
-    decode = jax.jit(functools.partial(model.decode_step_paged, rcfg=rcfg,
-                                       seq_cap=max_seq), donate_argnums=(1,))
-    logits, pool = prefill(params, cache0, pool0,
-                           jnp.asarray([prompt], jnp.int32))
-    rows = [logits[0]]
-    tables = jnp.arange(1, nb + 1, dtype=jnp.int32)[None]
-    for t, tok in enumerate(forced):
-        logits, pool = decode(params, pool, jnp.asarray([[tok]], jnp.int32),
-                              jnp.asarray([len(prompt) + t], jnp.int32),
-                              tables)
-        rows.append(logits[0])
-    return np.asarray(jnp.stack(rows), np.float32)
+    engine._decode_fn = decode_fn
+    return rows, live
 
 
 def rel_err(got, want) -> float:
@@ -175,6 +239,7 @@ def rel_err(got, want) -> float:
 
 def one_chip(cfg, rcfg, phases):
     import jax
+    import numpy as np
     from repro.models import get_model
     from repro.quant import build_variants
     from repro.serving import EngineConfig, ServingEngine
@@ -196,9 +261,11 @@ def one_chip(cfg, rcfg, phases):
     log(f"[engine] kv_layout={engine.kv_layout} blocks={engine.block_pool.num_blocks}"
         f" block_size={engine.block_size} max_batch={engine.max_batch}"
         f" max_seq={engine.max_seq}")
+    engine_rows, live = record_decode_logits(engine, PROBE_STEPS)
 
-    prompts = make_prompts(10, cfg.vocab_size, SEED)
-    waves = (("q8", prompts[:4]), ("q8", prompts[4:7]), ("q4", prompts[7:]))
+    # a full batch cold, a prefix-hit wave, then a wave on Q4 after the swap
+    prompts = make_prompts(15, cfg.vocab_size, SEED)
+    waves = (("q8", prompts[:8]), ("q8", prompts[8:11]), ("q4", prompts[11:]))
     outputs = {}
     asked = emitted = 0
     for i, (variant, wave) in enumerate(waves):
@@ -207,6 +274,7 @@ def one_chip(cfg, rcfg, phases):
             engine.swap_params(variants[variant], variant)
             phases.end(f"swap_params -> {variant}", engine.params)
         hits0 = engine.prefix_cache.hits
+        del live[:]
         phases.start()
         outs = serve(client, wave)
         phases.end(f"wave {i} ({variant}, {len(wave)} requests)", engine.pool)
@@ -214,7 +282,10 @@ def one_chip(cfg, rcfg, phases):
         asked += NEW_TOKENS * len(wave)
         emitted += sum(len(o) for o in outs)
         log(f"[serve] wave {i} {variant}: prefix-cache hits "
-            f"{engine.prefix_cache.hits - hits0}/{len(wave)}")
+            f"{engine.prefix_cache.hits - hits0}/{len(wave)}, "
+            f"most live slots in a decode step {max(live)}")
+        check(max(live) == len(wave), f"wave {i} never decoded all its "
+              f"{len(wave)} requests in one step")
     log(f"[serve] tokens emitted {emitted} / asked {asked}; "
         f"engine.tokens_emitted={engine.tokens_emitted}")
     log(f"[serve] swap_count={engine.swap_count} "
@@ -226,31 +297,50 @@ def one_chip(cfg, rcfg, phases):
     check(engine.prefix_cache.hits >= len(waves[1][1]),
           "the second wave did not hit the cached tool prefix")
 
-    # logit check: one cold-prefilled and one prefix-hit Q8 row, one Q4 row
-    ref_rcfg = dataclasses.replace(rcfg, use_pallas=False, interpret=False)
+    # logit check, every served request: the engine's prefill logits (kept
+    # with its prefix-cache entry) and its first PROBE_STEPS batched decode
+    # steps' rows, against the XLA reference fed the engine's own tokens
+    ref = Reference(model, dataclasses.replace(rcfg, use_pallas=False, interpret=False),
+                    max_seq=econfig.max_seq, block_size=econfig.block_size)
     worst = 0.0
-    for row in (0, 4, 7):
-        prompt = prompts[row]
+    clean = {}
+    phases.start()
+    for row, prompt in enumerate(prompts):
         variant, out = outputs[tuple(prompt)]
-        params = variants[variant]
-        phases.start()
         entry = engine.prefix_cache.lookup(prompt, salt=variant)
         check(entry is not None and entry.last_logits is not None,
-              "the engine kept no prefill logits for a served row")
-        forced = out[:PROBE_STEPS]
-        kw = dict(max_seq=econfig.max_seq, block_size=econfig.block_size)
-        ref = probe_logits(model, params, ref_rcfg, prompt, forced, **kw)
-        ker = probe_logits(model, params, rcfg, prompt, forced, **kw)
-        e_prefill = rel_err(entry.last_logits, ref[0])
-        e_decode = rel_err(ker[1:], ref[1:])
-        e_probe = rel_err(ker[:1], ref[:1])
-        phases.end(f"logit check (request {row}, {variant})")
-        log(f"[logits] request {row} {variant}: engine prefill vs reference {e_prefill:.3e}; "
-            f"kernel prefill {e_probe:.3e}, decode steps 1-{PROBE_STEPS} "
-            f"{e_decode:.3e} (tolerance {LOGIT_TOL})")
-        worst = max(worst, e_prefill, e_probe, e_decode)
+              f"the engine kept no prefill logits for request {row}")
+        got = engine_rows.get(tuple(prompt), [])
+        check(len(got) == PROBE_STEPS,
+              f"request {row}: {len(got)} decode rows recorded, want {PROBE_STEPS}")
+        want = ref.logits(variants[variant], prompt, out[:PROBE_STEPS])
+        e_prefill = rel_err(entry.last_logits, want[0])
+        e_decode = rel_err(np.stack(got), want[1:])
+        log(f"[logits] request {row} {variant}: prefill {e_prefill:.3e}, "
+            f"decode steps 1-{PROBE_STEPS} {e_decode:.3e}")
+        worst = max(worst, e_prefill, e_decode)
+        clean[row] = want
+    phases.end(f"logit check ({len(prompts)} requests)")
     log(f"[logits] max relative logit error {worst:.3e} (tolerance {LOGIT_TOL})")
     check(worst <= LOGIT_TOL, f"logit error {worst:.3e} above {LOGIT_TOL}")
+
+    # controls: the same check must fail on each planted fault, on a Q8 and
+    # a Q4 request
+    phases.start()
+    for row in (0, len(prompts) - 1):
+        variant, out = outputs[tuple(prompts[row])]
+        for fault in FAULTS:
+            params = variants[variant]
+            if fault == FAULTS[0]:
+                params = misread_scales(params)
+            bad = ref.logits(params, prompts[row], out[:PROBE_STEPS],
+                             fault=None if fault == FAULTS[0] else fault)
+            err = rel_err(bad, clean[row])
+            del params, bad
+            log(f"[control] request {row} {variant}, {fault}: {err:.3e}")
+            check(err > LOGIT_TOL, f"the logit check cannot see a {fault} "
+                  f"({err:.3e} <= {LOGIT_TOL})")
+    phases.end("fault controls")
 
 
 def four_chips(cfg, rcfg, phases):

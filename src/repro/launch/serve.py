@@ -6,8 +6,8 @@ engine.
 
 By default the model is the reduced config (d_model 64), which runs on a
 CPU. ``--full-width`` serves the architecture at its published widths
-(Qwen2-7B: 28 layers, d 3584, vocab 152064) with both variants built piece
-by piece from the seed (`quant.build_variants`) — for one TPU v5e.
+(Qwen2-7B: 28 layers, d 3584, vocab 152064) — for one TPU v5e. Either way
+both variants are built piece by piece from the seed (`quant.build_variants`).
 
 With ``--workers N`` the same query stream is served by N worker PROCESSES
 behind the engine control protocol (launch/workers.py): each worker builds
@@ -33,10 +33,9 @@ from repro.core.power import PowerModel
 from repro.data.workload import build_catalog, FunctionCallWorkload
 from repro.launch.compile_cache import enable_compile_cache
 from repro.models import get_model
-from repro.quant import build_variants, quantize_tree
+from repro.quant import build_variants
 from repro.serving import (EngineConfig, EngineStats, ServingEngine,
                            SessionRequest, WorkerSpec)
-from repro.sharding.param import init_params
 
 
 def _prompt_for(text: str, vocab_size: int):
@@ -81,15 +80,7 @@ def main():
     else:
         rcfg = RuntimeConfig()
         model = get_model(cfg)
-        spec = model.param_spec()
-        if args.full_width:
-            variants = build_variants(spec, jax.random.PRNGKey(0))
-        else:
-            params = init_params(spec, jax.random.PRNGKey(0))
-            variants = {
-                "q8": quantize_tree(params, spec, "q8"),
-                "q4": quantize_tree(params, spec, "q4"),
-            }
+        variants = build_variants(model.param_spec(), jax.random.PRNGKey(0))
         engine = ServingEngine(cfg, variants["q8"], rcfg, config=econfig)
         engine.variant_name = "q8"
         client = engine.client()

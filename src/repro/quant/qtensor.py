@@ -284,8 +284,10 @@ def build_variants(spec, key, fmts=("q8", "q4"), *, sharding=None):
     one piece (see `_pieces`) rather than the model (15 GB in bf16 for a
     7B-class model, which does not fit beside its variants on a 16 GB chip).
 
-    Returns {fmt: tree} shaped like `quant_spec(spec, fmt)`; "bf16" is the
-    unquantized tree the pieces were drawn from. Leaves no format quantizes
+    Returns {fmt: tree} shaped like `quant_spec(spec, fmt)`. "bf16" is the
+    unquantized tree the pieces were drawn from: it exists to verify the
+    quantized trees against `quantize_tree`, and since it is the whole bf16
+    tree it only fits at reduced widths. Leaves no format quantizes
     (norms, biases, the embedding table) are one array shared by every
     variant. `sharding` is a replicated placement (e.g. over a data mesh)
     every piece is drawn and stored with; None means the default device."""

@@ -158,3 +158,18 @@ def test_step_summary_renders_table(tmp_path):
     assert "| suite | metric | value |" in md
     assert "decode_tps@4" in md and "prefix_hit_rate" in md
     assert "no benchmark JSON" in render(str(tmp_path / "empty"))
+
+
+def test_kernels_suite_runs_on_cpu():
+    """`benchmarks/run.py kernels` drives every kernel through the
+    interpreter on the CPU and emits one row per kernel case."""
+    from benchmarks import common, kernels_bench
+    start = len(common.ROWS)
+    kernels_bench.run()
+    names = [r[0] for r in common.ROWS[start:]]
+    for kernel in ("quant_matmul/q8", "quant_matmul/q4",
+                   "flash_attention/causal", "flash_attention/window",
+                   "ssd/chunked", "paged_attention/bf16",
+                   "paged_attention/int8", "topk_sim/"):
+        assert any(n.startswith(f"kernels/{kernel}") for n in names), kernel
+    assert len(names) == 8
