@@ -1,8 +1,7 @@
-"""Shared benchmark scaffolding: timing + CSV emission."""
+"""Shared benchmark scaffolding: CSV emission."""
 from __future__ import annotations
 
-import time
-from typing import Callable, List, Tuple
+from typing import List, Tuple
 
 ROWS: List[Tuple[str, float, str]] = []
 
@@ -11,13 +10,3 @@ def emit(name: str, us_per_call: float, derived: str = ""):
     ROWS.append((name, us_per_call, derived))
     print(f"{name},{us_per_call:.3f},{derived}")
 
-
-def timed(name: str, fn: Callable, *, repeats: int = 3, derived_fn=None):
-    fn()                                     # warmup / compile
-    t0 = time.perf_counter()  # cc-lint: disable=CC001 -- real wall-clock is the measurement here
-    out = None
-    for _ in range(repeats):
-        out = fn()
-    us = (time.perf_counter() - t0) / repeats * 1e6  # cc-lint: disable=CC001 -- real wall-clock is the measurement here
-    emit(name, us, derived_fn(out) if derived_fn else "")
-    return out

@@ -4,12 +4,15 @@ moved per output and the theoretical speedup vs the bf16 path on v5e).
 """
 from __future__ import annotations
 
+import time
+from typing import Callable
+
 import jax
 import jax.numpy as jnp
 
 import numpy as np
 
-from benchmarks.common import timed
+from benchmarks.common import emit
 from repro.common.hardware import TPU_V5E
 from repro.quant import quantize
 from repro.kernels.quant_matmul import ops as qm_ops
@@ -17,6 +20,19 @@ from repro.kernels.flash_attention import ops as fa_ops
 from repro.kernels.paged_attention import ops as pa_ops, ref as pa_ref
 from repro.kernels.ssd import ops as ssd_ops
 from repro.kernels.topk_sim import ops as tk_ops
+
+
+def timed(name: str, fn: Callable, *, repeats: int = 3, derived_fn=None):
+    """Mean wall time of `fn()` over `repeats` calls after one warm-up
+    call, each waited on with `block_until_ready`; emitted as a CSV row."""
+    jax.block_until_ready(fn())              # warm-up / compile
+    t0 = time.perf_counter()  # cc-lint: disable=CC001 -- real wall-clock is the measurement here
+    out = None
+    for _ in range(repeats):
+        out = jax.block_until_ready(fn())
+    us = (time.perf_counter() - t0) / repeats * 1e6  # cc-lint: disable=CC001 -- real wall-clock is the measurement here
+    emit(name, us, derived_fn(out) if derived_fn else "")
+    return out
 
 
 def paged_attention_bench(quiet: bool = False):
@@ -64,8 +80,7 @@ def paged_attention_bench(quiet: bool = False):
     def bench(name, fn, derived):
         if quiet:
             return fn()
-        return timed(name, lambda: jax.block_until_ready(fn()),
-                     derived_fn=lambda _: derived)
+        return timed(name, fn, derived_fn=lambda _: derived)
 
     got = bench(
         f"kernels/paged_attention/bf16_b{B}_nb{nb}_splits{splits}",
@@ -111,8 +126,7 @@ def run():
         wbytes = t.nbytes()
         bf16_bytes = K * N * 2
         timed(f"kernels/quant_matmul/{fmt}_{M}x{K}x{N}",
-              lambda: jax.block_until_ready(
-                  qm_ops.quant_matmul(x, t, interpret=True)),
+              lambda: qm_ops.quant_matmul(x, t, interpret=True),
               derived_fn=lambda _: (
                   f"hbm_bytes={wbytes} vs bf16={bf16_bytes} "
                   f"speedup_mem_bound={bf16_bytes/wbytes:.2f}x "
@@ -124,14 +138,12 @@ def run():
     v = jax.random.normal(key, (B, S, Kh, H), jnp.bfloat16)
     flops = 4 * B * S * (S / 2) * Nh * H
     timed(f"kernels/flash_attention/causal_{S}",
-          lambda: jax.block_until_ready(
-              fa_ops.flash_attention(q, k, v, interpret=True)),
+          lambda: fa_ops.flash_attention(q, k, v, interpret=True),
           derived_fn=lambda _: (
               f"flops={flops:.2e} v5e_t_us={flops/TPU_V5E.peak_flops*1e6:.2f} "
               "o_s_memory=no_s2_materialization"))
     timed(f"kernels/flash_attention/window_{S}w128",
-          lambda: jax.block_until_ready(
-              fa_ops.flash_attention(q, k, v, window=128, interpret=True)),
+          lambda: fa_ops.flash_attention(q, k, v, window=128, interpret=True),
           derived_fn=lambda _: "block_skip=sub_quadratic_local_layers")
 
     Bs, Ss, Hh, P, G, Nst = 1, 512, 4, 64, 1, 64
@@ -142,8 +154,7 @@ def run():
     Cm = jax.random.normal(key, (Bs, Ss, G, Nst)) * 0.3
     ssd_flops = Bs * Ss * Hh * (2 * 128 * Nst + 2 * 128 * P + 4 * Nst * P)
     timed(f"kernels/ssd/chunked_{Ss}",
-          lambda: jax.block_until_ready(
-              ssd_ops.ssd(xs, dt, A, Bm, Cm, interpret=True)),
+          lambda: ssd_ops.ssd(xs, dt, A, Bm, Cm, interpret=True),
           derived_fn=lambda _: (
               f"flops={ssd_flops:.2e} "
               f"v5e_t_us={ssd_flops/TPU_V5E.peak_flops*1e6:.3f}"))
@@ -155,8 +166,7 @@ def run():
     qs = jax.random.normal(key, (4, 128))
     sim_bytes = 2048 * 128 * 4
     timed("kernels/topk_sim/2048x128",
-          lambda: jax.block_until_ready(
-              tk_ops.topk_tools(tools, qs, k=8, interpret=True)),
+          lambda: tk_ops.topk_tools(tools, qs, k=8, interpret=True),
           derived_fn=lambda _: (
               f"hbm_bytes={sim_bytes} (m x N sims never materialized) "
               f"v5e_t_us={sim_bytes/TPU_V5E.hbm_bandwidth*1e6:.3f}"))

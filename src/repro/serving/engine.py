@@ -101,6 +101,7 @@ from repro.serving.sampler import sample_tokens
 from repro.serving.scheduler import (
     CANCELLED, DONE, EngineStallError, PoolExhaustedError, RequestHandle,
     RUNNING, Scheduler, SessionRequest, TERMINAL, WAITING)
+from repro.serving.tracing import StepTracer
 from repro.sharding.param import ParamDef, init_params
 from repro.sharding.rules import (SERVING_RULES, activate_mesh, activate_rules,
                                   logical_sharding)
@@ -577,6 +578,9 @@ class ServingEngine:
                                 and paged_attention_uses_fallback(rcfg))
         self.kernel_fallbacks = 0
         self.step_log: List[Dict] = []
+        # profiler spans and each step's `host`/`compiles` record
+        self._tracer = StepTracer(clock)
+        self._phase = self._tracer.phase
 
     def _exec_key(self, kind: str, *extra) -> tuple:
         """Process-wide executable identity: everything the jitted impls read
@@ -770,32 +774,49 @@ class ServingEngine:
         window) or run one batched decode step. With chunking enabled the
         step alternates pending prefill work with a decode step for the
         residents, so a long prompt admits incrementally instead of stalling
-        every resident stream at once. Returns requests completed this step."""
+        every resident stream at once. Returns requests completed this step.
+
+        Each step is one `engine.step` profiler span, and its `step_log`
+        entry carries `host` (seconds per phase) and `compiles`
+        (serving/tracing.py)."""
+        if not self.has_work():
+            return []
+        n = len(self.step_log)
+        self._tracer.begin()
+        try:
+            return self._step()
+        finally:
+            self._tracer.end(self.step_log[n] if len(self.step_log) > n
+                             else None, n)
+
+    def _step(self) -> List[Request]:
         t0 = self.clock()
         # who was resident when the step started: prefill-kind steps stall
         # exactly these streams, and the executor charges them the step's
         # dt/energy share (see EngineExecutor._attribute_steps)
         resident_rids = [s.rid for s in self.slots if s is not None]
-        for req in self.scheduler.expire_due(t0):
-            self._release_chunk(req)
         completed: List[Request] = []
         work: Optional[Dict] = None
         spec: Optional[Dict] = None
-        if self.prefill_chunk is None or self._prefer_prefill \
-                or not self.active:
-            work = self._prefill_work()
-        if work is None and not self.active \
-                and self.prefill_chunk is not None:
-            # liveness fallback: the head is blocked (e.g. its final chunk
-            # needs a slot another parked dense chunk reserves) and nothing
-            # can decode — advance the first parked chunk so reserved slots
-            # drain. A bounded priority inversion, traded for progress.
-            head = self.scheduler.head()
-            for req in self.scheduler.waiting:
-                if req is not head and req.chunk_row is not None:
-                    work = self._chunk_step(req, self._free_slots())
-                    if work is not None:
-                        break
+        with self._phase("admit"):
+            for req in self.scheduler.expire_due(t0):
+                self._release_chunk(req)
+            if self.prefill_chunk is None or self._prefer_prefill \
+                    or not self.active:
+                work = self._prefill_work()
+            if work is None and not self.active \
+                    and self.prefill_chunk is not None:
+                # liveness fallback: the head is blocked (e.g. its final
+                # chunk needs a slot another parked dense chunk reserves)
+                # and nothing can decode — advance the first parked chunk so
+                # reserved slots drain. A bounded priority inversion, traded
+                # for progress.
+                head = self.scheduler.head()
+                for req in self.scheduler.waiting:
+                    if req is not head and req.chunk_row is not None:
+                        work = self._chunk_step(req, self._free_slots())
+                        if work is not None:
+                            break
         if work is not None:
             kind = work["kind"]
             tokens_this_step = work["tokens"]
@@ -852,26 +873,28 @@ class ServingEngine:
                 cost = float(self.step_cost_fn(kind, cost_tokens, occupancy))
             if cost > 0.0:
                 self.clock.advance(cost)
-        for req in completed:                # completion is at end of step
-            req.done_time = self.clock()
-            self.scheduler.note_done(req, req.done_time)
-        dt = max(self.clock() - t0, 1e-9)
-        self.tokens_emitted += tokens_this_step
-        rec = {
-            "kind": kind, "tokens": tokens_this_step, "dt": dt,
-            "tps": tokens_this_step / dt, "variant": self.variant_name,
-            "active": occupancy, "prompt_tokens": charged,
-            "cached_tokens": cached, "rids": rids,
-            "resident_rids": resident_rids,
-        }
-        if spec is not None:
-            # spec rows emit per-rid token *counts* — consumers that assume
-            # one token per rid per decode row (invariants, soak oracles)
-            # expand `emitted` instead
-            rec["drafted"] = spec["drafted"]
-            rec["accepted"] = spec["accepted"]
-            rec["emitted"] = spec["emitted"]
-        self.step_log.append(rec)
+        with self._phase("emit"):
+            for req in completed:            # completion is at end of step
+                req.done_time = self.clock()
+                self.scheduler.note_done(req, req.done_time)
+            self.tokens_emitted += tokens_this_step
+            rec = {
+                "kind": kind, "tokens": tokens_this_step,
+                "variant": self.variant_name,
+                "active": occupancy, "prompt_tokens": charged,
+                "cached_tokens": cached, "rids": rids,
+                "resident_rids": resident_rids,
+            }
+            if spec is not None:
+                # spec rows emit per-rid token *counts* — consumers that
+                # assume one token per rid per decode row (invariants, soak
+                # oracles) expand `emitted` instead
+                rec["drafted"] = spec["drafted"]
+                rec["accepted"] = spec["accepted"]
+                rec["emitted"] = spec["emitted"]
+            self.step_log.append(rec)
+        # read after every phase has closed, so the phases sum to at most dt
+        rec["dt"] = max(self.clock() - t0, 1e-9)
         return completed
 
     def run_until_drained(self, max_steps: int = 100000) -> List[Request]:
@@ -967,21 +990,27 @@ class ServingEngine:
         for req in reqs:
             self.scheduler.note_admitted(req, now)
         b = _bucket(max(len(r.prompt) for r in reqs), self.prompt_buckets)
-        toks = np.zeros((self.max_batch, b), np.int32)
-        for i, r in enumerate(reqs):
-            toks[i] = self._padded_row(r.prompt, b)
-        batch = self._prefill_batch(toks)
-        logits, cache_n, lengths_n = self._prefill_fn()(self.params, batch)
-        lengths_n = np.asarray(lengths_n)
+        with self._phase("inputs"):
+            toks = np.zeros((self.max_batch, b), np.int32)
+            for i, r in enumerate(reqs):
+                toks[i] = self._padded_row(r.prompt, b)
+            batch = self._prefill_batch(toks)
+        with self._phase("launch"):
+            logits, cache_n, lengths_n = self._prefill_fn()(self.params,
+                                                            batch)
+        with self._phase("fetch"):
+            lengths_n = np.asarray(lengths_n)
         for i, (req, slot) in enumerate(zip(reqs, free)):
-            self.cache = jax.tree.map(
-                lambda c, p: c.at[:, slot].set(p[:, i].astype(c.dtype))
-                if c.ndim >= 2 else c, self.cache, cache_n)
-            self.lengths = self.lengths.at[slot].set(int(lengths_n[i]))
+            with self._phase("launch"):
+                self.cache = jax.tree.map(
+                    lambda c, p: c.at[:, slot].set(p[:, i].astype(c.dtype))
+                    if c.ndim >= 2 else c, self.cache, cache_n)
+                self.lengths = self.lengths.at[slot].set(int(lengths_n[i]))
             self._place(req, slot, toks[i])
             tok = self._sample(logits[i:i + 1], req)
-            self._emit(req, slot, int(tok[0]))
-            self._slot_emit0[slot] = len(req.output)
+            with self._phase("emit"):
+                self._emit(req, slot, int(tok[0]))
+                self._slot_emit0[slot] = len(req.output)
         return reqs, sum(len(r.prompt) for r in reqs), 0
 
     def _place(self, req: Request, slot: int, row: np.ndarray):
@@ -1071,10 +1100,12 @@ class ServingEngine:
             else:
                 logits_c = self._prefill_suffix(compute, b)
             for i, r in enumerate(compute):
-                r["logits"] = np.asarray(logits_c[i])
-                self.prefix_cache.insert(r["row"], r["blocks"],
-                                         last_logits=r["logits"],
-                                         salt=self.variant_name)
+                with self._phase("fetch"):
+                    r["logits"] = np.asarray(logits_c[i])
+                with self._phase("emit"):
+                    self.prefix_cache.insert(r["row"], r["blocks"],
+                                             last_logits=r["logits"],
+                                             salt=self.variant_name)
         for r in full:
             r["logits"] = r["hit"].last_logits
 
@@ -1091,8 +1122,9 @@ class ServingEngine:
             self.lengths[slot] = b
             self._place(req, slot, r["row"])
             tok = self._sample(r["logits"][None, :], req)
-            self._emit(req, slot, int(tok[0]))
-            self._slot_emit0[slot] = len(req.output)
+            with self._phase("emit"):
+                self._emit(req, slot, int(tok[0]))
+                self._slot_emit0[slot] = len(req.output)
         self.prefill_tokens_total += charged + cached
         self.prefill_tokens_saved += cached
         return [r["req"] for r in rows], charged, cached
@@ -1135,15 +1167,18 @@ class ServingEngine:
             # `_chunk_needed` guarantees the first window cannot cover the
             # whole bucket, so no logits are needed here.
             W = _pow2(end, self.max_seq)
-            toks = np.zeros((self.max_batch, W), np.int32)
-            toks[0, :end] = row[:end]
-            _, cache_n, _ = self._prefill_fn()(self.params,
-                                               self._prefill_batch(toks))
-            dst = [req.chunk_blocks[p // bs] * bs + p % bs
-                   for p in range(end)]
-            self.pool = self._scatter_cache_fn(
-                self.pool, cache_n,
-                *self._scatter_idx(dst, [0] * end, list(range(end))))
+            with self._phase("inputs"):
+                toks = np.zeros((self.max_batch, W), np.int32)
+                toks[0, :end] = row[:end]
+                batch = self._prefill_batch(toks)
+            with self._phase("launch"):
+                _, cache_n, _ = self._prefill_fn()(self.params, batch)
+            with self._phase("inputs"):
+                dst = [req.chunk_blocks[p // bs] * bs + p % bs
+                       for p in range(end)]
+                idx = self._scatter_idx(dst, [0] * end, list(range(end)))
+            with self._phase("launch"):
+                self.pool = self._scatter_cache_fn(self.pool, cache_n, *idx)
             return None
         # middle/final window: the parked chain is the "cached prefix", the
         # window is a left-padded suffix at its exact absolute positions —
@@ -1153,23 +1188,26 @@ class ServingEngine:
         # monolithic prefill
         W = _pow2(nwin, b)
         nbp = _pow2(-(-start // bs), self.blocks_per_slot)
-        toks = np.zeros((self.max_batch, W), np.int32)
-        toks[0, W - nwin:] = row[start:end]
-        bids = np.zeros((self.max_batch, nbp), np.int32)
-        bids[0, :start // bs] = req.chunk_blocks[:start // bs]
-        plens = np.zeros((self.max_batch,), np.int32)
-        plens[0] = start
-        batch = self._prefill_batch(toks)
-        batch["positions"] = jnp.arange(end - W, end, dtype=jnp.int32)
-        logits, (k_win, v_win) = self._prefill_chunk_fn()(
-            self.params, self.pool, batch, jnp.asarray(bids),
-            jnp.asarray(plens), final)
-        dst = [req.chunk_blocks[p // bs] * bs + p % bs
-               for p in range(start, end)]
-        src_s = [p - (end - W) for p in range(start, end)]
-        self.pool = self._scatter_kv_fn(
-            self.pool, k_win, v_win,
-            *self._scatter_idx(dst, [0] * nwin, src_s))
+        with self._phase("inputs"):
+            toks = np.zeros((self.max_batch, W), np.int32)
+            toks[0, W - nwin:] = row[start:end]
+            bids = np.zeros((self.max_batch, nbp), np.int32)
+            bids[0, :start // bs] = req.chunk_blocks[:start // bs]
+            plens = np.zeros((self.max_batch,), np.int32)
+            plens[0] = start
+            batch = self._prefill_batch(toks)
+            batch["positions"] = jnp.arange(end - W, end, dtype=jnp.int32)
+            bids, plens = jnp.asarray(bids), jnp.asarray(plens)
+        with self._phase("launch"):
+            logits, (k_win, v_win) = self._prefill_chunk_fn()(
+                self.params, self.pool, batch, bids, plens, final)
+        with self._phase("inputs"):
+            dst = [req.chunk_blocks[p // bs] * bs + p % bs
+                   for p in range(start, end)]
+            src_s = [p - (end - W) for p in range(start, end)]
+            idx = self._scatter_idx(dst, [0] * nwin, src_s)
+        with self._phase("launch"):
+            self.pool = self._scatter_kv_fn(self.pool, k_win, v_win, *idx)
         return logits
 
     def _chunk_step_paged(self, req: Request,
@@ -1203,8 +1241,9 @@ class ServingEngine:
             # the request's refs while it extends them, CoW-shareable by
             # concurrent admissions of the same prefix, and plain evictable
             # cache if the chunk is dropped
-            self.prefix_cache.insert(row[:end], req.chunk_blocks,
-                                     salt=self.variant_name)
+            with self._phase("emit"):
+                self.prefix_cache.insert(row[:end], req.chunk_blocks,
+                                         salt=self.variant_name)
             self.scheduler.note_chunk_step(req)
             return {"kind": "prefill_chunk", "tokens": 0, "charged": charged,
                     "cached": 0, "rids": [req.rid]}
@@ -1212,10 +1251,12 @@ class ServingEngine:
         charged += max(0, len(req.prompt) - b)   # no free truncation discount
         slot = free[0]
         self.scheduler.note_admitted(req, self.clock())
-        logits = np.asarray(logits)
-        self.prefix_cache.insert(row, req.chunk_blocks,
-                                 last_logits=logits[0],
-                                 salt=self.variant_name)
+        with self._phase("fetch"):
+            logits = np.asarray(logits)
+        with self._phase("emit"):
+            self.prefix_cache.insert(row, req.chunk_blocks,
+                                     last_logits=logits[0],
+                                     salt=self.variant_name)
         if req.chunk_hit:
             self.prefix_cache.hits += 1
         else:
@@ -1229,8 +1270,9 @@ class ServingEngine:
         self.lengths[slot] = b
         self._place(req, slot, row)
         tok = self._sample(logits[0:1], req)
-        self._emit(req, slot, int(tok[0]))
-        self._slot_emit0[slot] = len(req.output)
+        with self._phase("emit"):
+            self._emit(req, slot, int(tok[0]))
+            self._slot_emit0[slot] = len(req.output)
         self._clear_chunk(req)
         return {"kind": "prefill", "tokens": 1, "charged": charged,
                 "cached": cached, "rids": [req.rid]}
@@ -1255,14 +1297,16 @@ class ServingEngine:
             # cold first window (never final, see _chunk_window): stock full
             # prefill of [0, end), window copied into the reserved stripe
             W = _pow2(end, self.max_seq)
-            toks = np.zeros((self.max_batch, W), np.int32)
-            toks[0, :end] = row[:end]
-            _, cache_n, _ = self._prefill_fn()(self.params,
-                                               self._prefill_batch(toks))
-            self.cache = jax.tree.map(
-                lambda c, p: c.at[:, slot, :end].set(
-                    p[:, 0, :end].astype(c.dtype)) if c.ndim >= 3 else c,
-                self.cache, cache_n)
+            with self._phase("inputs"):
+                toks = np.zeros((self.max_batch, W), np.int32)
+                toks[0, :end] = row[:end]
+                batch = self._prefill_batch(toks)
+            with self._phase("launch"):
+                _, cache_n, _ = self._prefill_fn()(self.params, batch)
+                self.cache = jax.tree.map(
+                    lambda c, p: c.at[:, slot, :end].set(
+                        p[:, 0, :end].astype(c.dtype)) if c.ndim >= 3 else c,
+                    self.cache, cache_n)
         else:
             from repro.models.transformer import quantize_kv_for_cache
             p_len = _pow2(start, self.max_seq)
@@ -1270,27 +1314,31 @@ class ServingEngine:
             # the prefix view is cache[:, :, :p_len] — batch rows align with
             # cache slots, so the window MUST ride in row `slot` to attend the
             # reserved stripe (row 0 would read slot 0's resident KV instead)
-            toks = np.zeros((self.max_batch, W), np.int32)
-            toks[slot, W - nwin:] = row[start:end]
-            plens = np.zeros((self.max_batch,), np.int32)
-            plens[slot] = start
-            batch = self._prefill_batch(toks)
-            batch["positions"] = jnp.arange(end - W, end, dtype=jnp.int32)
-            logits, (k_win, v_win) = self._dense_chunk_fn()(
-                self.params, self.cache, batch, jnp.asarray(plens),
-                p_len, final)
-            entry = quantize_kv_for_cache("k_scale" in self.cache,
-                                          k_win, v_win)
-            for key, val in entry.items():
-                self.cache[key] = self.cache[key].at[
-                    :, slot, start:end].set(
-                        val[:, slot, W - nwin:].astype(self.cache[key].dtype))
+            with self._phase("inputs"):
+                toks = np.zeros((self.max_batch, W), np.int32)
+                toks[slot, W - nwin:] = row[start:end]
+                plens = np.zeros((self.max_batch,), np.int32)
+                plens[slot] = start
+                batch = self._prefill_batch(toks)
+                batch["positions"] = jnp.arange(end - W, end, dtype=jnp.int32)
+                plens = jnp.asarray(plens)
+            with self._phase("launch"):
+                logits, (k_win, v_win) = self._dense_chunk_fn()(
+                    self.params, self.cache, batch, plens, p_len, final)
+                entry = quantize_kv_for_cache("k_scale" in self.cache,
+                                              k_win, v_win)
+                for key, val in entry.items():
+                    self.cache[key] = self.cache[key].at[
+                        :, slot, start:end].set(
+                            val[:, slot, W - nwin:].astype(
+                                self.cache[key].dtype))
         req.chunk_done = end
         # advance the stripe's fill mark: an interleaved dense decode step
         # blindly writes its per-row KV at lengths[slot] for EVERY row, so
         # pointing it at the next window's first position makes the garbage
         # write land where the next chunk overwrites it
-        self.lengths = self.lengths.at[slot].set(end)
+        with self._phase("launch"):
+            self.lengths = self.lengths.at[slot].set(end)
         pad = b - min(len(req.prompt), b)
         charged = max(0, end - max(start, pad))
         if not final:
@@ -1301,9 +1349,12 @@ class ServingEngine:
         self.scheduler.note_admitted(req, self.clock())
         self._chunk_slots.discard(slot)
         self._place(req, slot, row)
-        tok = self._sample(np.asarray(logits)[slot:slot + 1], req)
-        self._emit(req, slot, int(tok[0]))
-        self._slot_emit0[slot] = len(req.output)
+        with self._phase("fetch"):
+            logits = np.asarray(logits)
+        tok = self._sample(logits[slot:slot + 1], req)
+        with self._phase("emit"):
+            self._emit(req, slot, int(tok[0]))
+            self._slot_emit0[slot] = len(req.output)
         self._clear_chunk(req)
         return {"kind": "prefill", "tokens": 1, "charged": charged,
                 "cached": 0, "rids": [req.rid]}
@@ -1404,14 +1455,17 @@ class ServingEngine:
         if blocks is None:                   # unreachable after _reclaim
             return -1
         W = _pow2(L, self.max_seq)
-        toks = np.zeros((self.max_batch, W), np.int32)
-        toks[0, :L] = row
-        _, cache_n, _ = self._prefill_fn()(self.params,
-                                           self._prefill_batch(toks))
-        dst = [blocks[p // bs] * bs + p % bs for p in range(L)]
-        self.pool = self._scatter_cache_fn(
-            self.pool, cache_n,
-            *self._scatter_idx(dst, [0] * L, list(range(L))))
+        with self._phase("inputs"):
+            toks = np.zeros((self.max_batch, W), np.int32)
+            toks[0, :L] = row
+            batch = self._prefill_batch(toks)
+        with self._phase("launch"):
+            _, cache_n, _ = self._prefill_fn()(self.params, batch)
+        with self._phase("inputs"):
+            dst = [blocks[p // bs] * bs + p % bs for p in range(L)]
+            idx = self._scatter_idx(dst, [0] * L, list(range(L)))
+        with self._phase("launch"):
+            self.pool = self._scatter_cache_fn(self.pool, cache_n, *idx)
         self.slot_blocks[slot] = list(blocks)
         self.block_tables[slot] = 0
         self.block_tables[slot, :nb] = blocks
@@ -1452,20 +1506,24 @@ class ServingEngine:
     def _prefill_cold(self, compute, b: int):
         """No cached prefix anywhere in the batch: run the stock full-row
         prefill and scatter every position into the rows' blocks."""
-        toks = np.zeros((self.max_batch, b), np.int32)
-        for i, r in enumerate(compute):
-            toks[i] = r["row"]
-        logits, cache_n, _ = self._prefill_fn()(self.params,
-                                                self._prefill_batch(toks))
-        dst, src_b, src_s = [], [], []
-        for i, r in enumerate(compute):
-            for p in range(b):
-                dst.append(r["blocks"][p // self.block_size]
-                           * self.block_size + p % self.block_size)
-                src_b.append(i)
-                src_s.append(p)
-        self.pool = self._scatter_cache_fn(
-            self.pool, cache_n, *self._scatter_idx(dst, src_b, src_s))
+        with self._phase("inputs"):
+            toks = np.zeros((self.max_batch, b), np.int32)
+            for i, r in enumerate(compute):
+                toks[i] = r["row"]
+            batch = self._prefill_batch(toks)
+        with self._phase("launch"):
+            logits, cache_n, _ = self._prefill_fn()(self.params, batch)
+        with self._phase("inputs"):
+            dst, src_b, src_s = [], [], []
+            for i, r in enumerate(compute):
+                for p in range(b):
+                    dst.append(r["blocks"][p // self.block_size]
+                               * self.block_size + p % self.block_size)
+                    src_b.append(i)
+                    src_s.append(p)
+            idx = self._scatter_idx(dst, src_b, src_s)
+        with self._phase("launch"):
+            self.pool = self._scatter_cache_fn(self.pool, cache_n, *idx)
         return logits
 
     def _prefill_suffix(self, compute, b: int):
@@ -1480,28 +1538,32 @@ class ServingEngine:
         s_suf = _pow2(b - min(r["cached_len"] for r in compute), b)
         p_len = max(r["cached_len"] for r in compute)
         nbp = _pow2(-(-p_len // bs), self.blocks_per_slot)
-        toks = np.zeros((self.max_batch, s_suf), np.int32)
-        bids = np.zeros((self.max_batch, nbp), np.int32)
-        plens = np.zeros((self.max_batch,), np.int32)
-        for i, r in enumerate(compute):
-            cl = r["cached_len"]
-            suf = r["row"][cl:]
-            toks[i, s_suf - len(suf):] = suf
-            bids[i, :cl // bs] = r["blocks"][:cl // bs]
-            plens[i] = cl
-        batch = self._prefill_batch(toks)
-        batch["positions"] = jnp.arange(b - s_suf, b, dtype=jnp.int32)
-        logits, (k_suf, v_suf) = self._prefill_prefix_fn()(
-            self.params, self.pool, batch, jnp.asarray(bids),
-            jnp.asarray(plens))
-        dst, src_b, src_s = [], [], []
-        for i, r in enumerate(compute):
-            for p in range(r["cached_len"], b):
-                dst.append(r["blocks"][p // bs] * bs + p % bs)
-                src_b.append(i)
-                src_s.append(p - (b - s_suf))
-        self.pool = self._scatter_kv_fn(
-            self.pool, k_suf, v_suf, *self._scatter_idx(dst, src_b, src_s))
+        with self._phase("inputs"):
+            toks = np.zeros((self.max_batch, s_suf), np.int32)
+            bids = np.zeros((self.max_batch, nbp), np.int32)
+            plens = np.zeros((self.max_batch,), np.int32)
+            for i, r in enumerate(compute):
+                cl = r["cached_len"]
+                suf = r["row"][cl:]
+                toks[i, s_suf - len(suf):] = suf
+                bids[i, :cl // bs] = r["blocks"][:cl // bs]
+                plens[i] = cl
+            batch = self._prefill_batch(toks)
+            batch["positions"] = jnp.arange(b - s_suf, b, dtype=jnp.int32)
+            bids, plens = jnp.asarray(bids), jnp.asarray(plens)
+        with self._phase("launch"):
+            logits, (k_suf, v_suf) = self._prefill_prefix_fn()(
+                self.params, self.pool, batch, bids, plens)
+        with self._phase("inputs"):
+            dst, src_b, src_s = [], [], []
+            for i, r in enumerate(compute):
+                for p in range(r["cached_len"], b):
+                    dst.append(r["blocks"][p // bs] * bs + p % bs)
+                    src_b.append(i)
+                    src_s.append(p - (b - s_suf))
+            idx = self._scatter_idx(dst, src_b, src_s)
+        with self._phase("launch"):
+            self.pool = self._scatter_kv_fn(self.pool, k_suf, v_suf, *idx)
         return logits
 
     @staticmethod
@@ -1552,16 +1614,24 @@ class ServingEngine:
         """One batched decode step over the resident slots. Returns
         (tokens emitted, rids of the slots that actually decoded — block
         pressure may preempt slots out of the step)."""
-        last = np.zeros((self.max_batch, 1), np.int32)
-        for i, req in enumerate(self.slots):
-            if req is not None:
-                last[i, 0] = req.output[-1] if req.output else (
-                    req.prompt[-1] if req.prompt else 0)
+        with self._phase("inputs"):
+            last = np.zeros((self.max_batch, 1), np.int32)
+            for i, req in enumerate(self.slots):
+                if req is not None:
+                    last[i, 0] = req.output[-1] if req.output else (
+                        req.prompt[-1] if req.prompt else 0)
         if self.kv_layout == "paged":
-            self._prepare_decode_blocks()
-            logits, self.pool = self._decode_fn()(
-                self.params, self.pool, jnp.asarray(last),
-                jnp.asarray(self.lengths), jnp.asarray(self.block_tables))
+            with self._phase("blocks"):
+                self._prepare_decode_blocks()
+            with self._phase("inputs"):
+                # `lengths` is copied: it changes in place below, while the
+                # device may still read the uploaded array (zero-copy on
+                # the CPU)
+                args = (jnp.asarray(last), jnp.asarray(self.lengths.copy()),
+                        jnp.asarray(self.block_tables))
+            with self._phase("launch"):
+                logits, self.pool = self._decode_fn()(self.params, self.pool,
+                                                      *args)
             # saturate at max_seq: a full context drops further KV writes
             # cleanly (decode keeps attending the intact prompt) instead of
             # stepping back and overwriting the last real position
@@ -1569,28 +1639,30 @@ class ServingEngine:
                 if req is not None:
                     self.lengths[i] = min(self.lengths[i] + 1, self.max_seq)
         else:
-            logits, self.cache = self._decode_fn()(self.params, self.cache,
-                                                   jnp.asarray(last),
-                                                   self.lengths)
-            self.lengths = jnp.where(
-                jnp.asarray([s is not None for s in self.slots]),
-                jnp.minimum(self.lengths + 1, self.max_seq), self.lengths)
+            with self._phase("inputs"):
+                last = jnp.asarray(last)
+            with self._phase("launch"):
+                logits, self.cache = self._decode_fn()(
+                    self.params, self.cache, last, self.lengths)
+                self.lengths = jnp.where(
+                    jnp.asarray([s is not None for s in self.slots]),
+                    jnp.minimum(self.lengths + 1, self.max_seq), self.lengths)
+        live = [(i, req) for i, req in enumerate(self.slots)
+                if req is not None]
+        if live:
+            toks = self._sample(logits, live[0][1])
         emitted = 0
         rids: List[int] = []
-        toks = None
-        for i, req in enumerate(self.slots):
-            if req is None:
-                continue
-            if toks is None:
-                toks = np.asarray(self._sample(logits, req))
-            tok = int(toks[i])
-            self._emit(req, i, tok)
-            emitted += 1
-            rids.append(req.rid)
-            if tok == req.eos_id or len(req.output) >= req.max_new_tokens:
-                completed.append(req)        # done_time stamped at end of step
-                req.status = DONE
-                self._free_slot(i)
+        with self._phase("emit"):
+            for i, req in live:
+                tok = int(toks[i])
+                self._emit(req, i, tok)
+                emitted += 1
+                rids.append(req.rid)
+                if tok == req.eos_id or len(req.output) >= req.max_new_tokens:
+                    completed.append(req)    # done_time stamped at end of step
+                    req.status = DONE
+                    self._free_slot(i)
         return emitted, rids
 
     def _prepare_decode_blocks(self):
@@ -1620,7 +1692,8 @@ class ServingEngine:
                 new = self._decode_alloc(i)
                 if new is None:
                     continue
-                self.pool = self._copy_block_fn(self.pool, new, bid)
+                with self._phase("launch"):
+                    self.pool = self._copy_block_fn(self.pool, new, bid)
                 self.block_pool.decref(bid)
                 self.block_tables[i, blk] = new
                 self.slot_blocks[i][blk] = new
@@ -1663,7 +1736,9 @@ class ServingEngine:
         if L % bs:
             src = int(self.block_tables[i, L // bs])
             if src:                      # always true for a live slot
-                self.pool = self._copy_block_fn(self.pool, blocks[0], src)
+                with self._phase("launch"):
+                    self.pool = self._copy_block_fn(self.pool, blocks[0],
+                                                    src)
         return blocks
 
     def _spec_release_leases(self, i: int):
@@ -1691,46 +1766,55 @@ class ServingEngine:
         the same alloc/CoW rules as `_prepare_decode_blocks`."""
         bs, k = self.block_size, self.spec_k
         live = [(i, r) for i, r in enumerate(self.slots) if r is not None]
-        need = 0
-        for i, _ in live:
-            L = int(self.lengths[i])
-            if L + k + 1 > self.max_seq:
+        with self._phase("blocks"):
+            need = 0
+            for i, _ in live:
+                L = int(self.lengths[i])
+                if L + k + 1 > self.max_seq:
+                    return None
+                # leases span blocks L//bs .. (L+k-1)//bs; the canonical
+                # chain may need one block per boundary crossed by writes at
+                # [L, L+k] plus an alloc/CoW for the write block itself
+                need += (L + k - 1) // bs - L // bs + 1
+                need += (L + k) // bs - L // bs
+                bid = int(self.block_tables[i, L // bs])
+                if bid == 0 or self.block_pool.is_shared(bid):
+                    need += 1
+            if not self._spec_reserve(need):
                 return None
-            # leases span blocks L//bs .. (L+k-1)//bs; the canonical chain
-            # may need one block per boundary crossed by writes at [L, L+k]
-            # plus an alloc/CoW for the write block itself
-            need += (L + k - 1) // bs - L // bs + 1
-            need += (L + k) // bs - L // bs
-            bid = int(self.block_tables[i, L // bs])
-            if bid == 0 or self.block_pool.is_shared(bid):
-                need += 1
-        if not self._spec_reserve(need):
-            return None
 
         # -- draft: k greedy rounds under the draft variant ------------------
-        last0 = np.zeros((self.max_batch, 1), np.int32)
-        for i, r in live:
-            last0[i, 0] = r.output[-1] if r.output else (
-                r.prompt[-1] if r.prompt else 0)
-        draft_tables = self.block_tables.copy()
-        for i, _ in live:
-            L = int(self.lengths[i])
-            for j, bid in enumerate(self._spec_acquire_leases(i, L, k)):
-                draft_tables[i, L // bs + j] = bid
-        draft_lengths = self.lengths.copy()
-        draft_toks = np.zeros((self.max_batch, k), np.int32)
-        cur = last0.copy()
-        dfn = self._decode_fn(self.draft_variant)
-        tables_j = jnp.asarray(draft_tables)
+        with self._phase("inputs"):
+            last0 = np.zeros((self.max_batch, 1), np.int32)
+            for i, r in live:
+                last0[i, 0] = r.output[-1] if r.output else (
+                    r.prompt[-1] if r.prompt else 0)
+        with self._phase("blocks"):
+            draft_tables = self.block_tables.copy()
+            for i, _ in live:
+                L = int(self.lengths[i])
+                for j, bid in enumerate(self._spec_acquire_leases(i, L, k)):
+                    draft_tables[i, L // bs + j] = bid
+        with self._phase("inputs"):
+            draft_lengths = self.lengths.copy()
+            draft_toks = np.zeros((self.max_batch, k), np.int32)
+            cur = last0.copy()
+            dfn = self._decode_fn(self.draft_variant)
+            tables_j = jnp.asarray(draft_tables)
         for j in range(k):
-            logits, self.pool = dfn(self.draft_params, self.pool,
-                                    jnp.asarray(cur),
-                                    jnp.asarray(draft_lengths), tables_j)
+            with self._phase("inputs"):
+                args = (jnp.asarray(cur), jnp.asarray(draft_lengths),
+                        tables_j)
+            with self._phase("launch"):
+                logits, self.pool = dfn(self.draft_params, self.pool, *args)
             # raw argmax == sample_tokens at temperature 0, without
             # splitting self.key — parity with the plain path's key
             # evolution is irrelevant under greedy decoding (enforced by
             # _spec_ready)
-            nxt = np.asarray(jnp.argmax(logits, axis=-1), np.int32)
+            with self._phase("sample"):
+                nxt = jnp.argmax(logits, axis=-1)
+            with self._phase("fetch"):
+                nxt = np.asarray(nxt, np.int32)
             for i, _ in live:
                 draft_toks[i, j] = nxt[i]
                 cur[i, 0] = nxt[i]
@@ -1740,24 +1824,29 @@ class ServingEngine:
         W = k + 1
         nbp = _pow2(max(-(-int(self.lengths[i]) // bs) for i, _ in live),
                     self.blocks_per_slot)
-        toks = np.zeros((self.max_batch, W), np.int32)
-        poss = np.zeros((self.max_batch, W), np.int32)
-        bids = np.zeros((self.max_batch, nbp), np.int32)
-        plens = np.zeros((self.max_batch,), np.int32)
-        for i, _ in live:
-            L = int(self.lengths[i])
-            toks[i, 0] = last0[i, 0]
-            toks[i, 1:] = draft_toks[i]
-            poss[i] = np.arange(L, L + W)
-            nb = -(-L // bs)
-            bids[i, :nb] = self.block_tables[i, :nb]
-            plens[i] = L
-        batch = self._prefill_batch(toks)
-        batch["positions"] = jnp.asarray(poss)
-        logits, (k_win, v_win) = self._verify_fn()(
-            self.params, self.pool, batch, jnp.asarray(bids),
-            jnp.asarray(plens))
-        greedy = np.asarray(jnp.argmax(logits, axis=-1), np.int32)  # (B, W)
+        with self._phase("inputs"):
+            toks = np.zeros((self.max_batch, W), np.int32)
+            poss = np.zeros((self.max_batch, W), np.int32)
+            bids = np.zeros((self.max_batch, nbp), np.int32)
+            plens = np.zeros((self.max_batch,), np.int32)
+            for i, _ in live:
+                L = int(self.lengths[i])
+                toks[i, 0] = last0[i, 0]
+                toks[i, 1:] = draft_toks[i]
+                poss[i] = np.arange(L, L + W)
+                nb = -(-L // bs)
+                bids[i, :nb] = self.block_tables[i, :nb]
+                plens[i] = L
+            batch = self._prefill_batch(toks)
+            batch["positions"] = jnp.asarray(poss)
+            bids, plens = jnp.asarray(bids), jnp.asarray(plens)
+        with self._phase("launch"):
+            logits, (k_win, v_win) = self._verify_fn()(
+                self.params, self.pool, batch, bids, plens)
+        with self._phase("sample"):
+            greedy = jnp.argmax(logits, axis=-1)
+        with self._phase("fetch"):
+            greedy = np.asarray(greedy, np.int32)                # (B, W)
 
         # -- accept, commit canonical KV, reconcile leases -------------------
         drafted = k * len(live)
@@ -1799,7 +1888,8 @@ class ServingEngine:
                 elif self.block_pool.is_shared(bid):
                     new = self.block_pool.alloc()
                     assert new is not None, "spec CoW alloc underflowed"
-                    self.pool = self._copy_block_fn(self.pool, new, bid)
+                    with self._phase("launch"):
+                        self.pool = self._copy_block_fn(self.pool, new, bid)
                     self.block_pool.decref(bid)
                     self.block_tables[i, blk] = new
                     self.slot_blocks[i][blk] = new
@@ -1808,27 +1898,31 @@ class ServingEngine:
                 dst.append(bid * bs + p % bs)
                 src_b.append(i)
                 src_s.append(p - L)
-        self.pool = self._scatter_kv_fn(
-            self.pool, k_win, v_win, *self._scatter_idx(dst, src_b, src_s))
-        for i, _ in live:
-            self._spec_release_leases(i)
+        with self._phase("inputs"):
+            idx = self._scatter_idx(dst, src_b, src_s)
+        with self._phase("launch"):
+            self.pool = self._scatter_kv_fn(self.pool, k_win, v_win, *idx)
+        with self._phase("blocks"):
+            for i, _ in live:
+                self._spec_release_leases(i)
 
         emitted_total = 0
         rids: List[int] = []
         emitted: Dict[int, int] = {}
-        for (i, r), toks_out in zip(live, outs):
-            self.lengths[i] = min(int(self.lengths[i]) + len(toks_out),
-                                  self.max_seq)
-            for t in toks_out:
-                self._emit(r, i, t)
-            emitted_total += len(toks_out)
-            rids.append(r.rid)
-            emitted[r.rid] = len(toks_out)
-            if (toks_out[-1] == r.eos_id
-                    or len(r.output) >= r.max_new_tokens):
-                completed.append(r)      # done_time stamped at end of step
-                r.status = DONE
-                self._free_slot(i)
+        with self._phase("emit"):
+            for (i, r), toks_out in zip(live, outs):
+                self.lengths[i] = min(int(self.lengths[i]) + len(toks_out),
+                                      self.max_seq)
+                for t in toks_out:
+                    self._emit(r, i, t)
+                emitted_total += len(toks_out)
+                rids.append(r.rid)
+                emitted[r.rid] = len(toks_out)
+                if (toks_out[-1] == r.eos_id
+                        or len(r.output) >= r.max_new_tokens):
+                    completed.append(r)  # done_time stamped at end of step
+                    r.status = DONE
+                    self._free_slot(i)
         self.draft_tokens += drafted
         self.accepted_tokens += accepted
         self.scheduler.note_spec_step()
@@ -1850,10 +1944,15 @@ class ServingEngine:
         else:
             self.lengths = self.lengths.at[i].set(0)
 
-    def _sample(self, logits, req: Request):
-        self.key, sub = jax.random.split(self.key)
-        return sample_tokens(jnp.asarray(logits), sub,
-                             temperature=req.temperature)
+    def _sample(self, logits, req: Request) -> np.ndarray:
+        """Sample one token per row of `logits` on the device and fetch
+        them to the host."""
+        with self._phase("sample"):
+            self.key, sub = jax.random.split(self.key)
+            toks = sample_tokens(jnp.asarray(logits), sub,
+                                 temperature=req.temperature)
+        with self._phase("fetch"):
+            return np.asarray(toks)
 
     def _emit(self, req: Request, slot: int, tok: int):
         if req.first_token_time is None:
