@@ -1,6 +1,12 @@
 """Kernel microbenches (interpret-mode wall time is NOT TPU performance —
 the derived column reports the roofline-model numbers that matter: bytes
 moved per output and the theoretical speedup vs the bf16 path on v5e).
+
+    PYTHONPATH=src:. python benchmarks/kernels_bench.py                # CPU
+    PYTHONPATH=src:. python benchmarks/kernels_bench.py --chip OUT.json  # TPU
+
+`--chip` times the compiled Q8 kernel at the published Qwen2-7B shapes
+(`q8_chip_sweep`) and writes every row to OUT.json.
 """
 from __future__ import annotations
 
@@ -172,5 +178,104 @@ def run():
               f"v5e_t_us={sim_bytes/TPU_V5E.hbm_bandwidth*1e6:.3f}"))
 
 
+# published Qwen2-7B Q8 products: (name, K, N, layers in the stack, the
+# row counts the model multiplies it at). The LM head is one matrix; it
+# gets two copies here, since a call that reads the same layer in every
+# loop step does not depend on the loop and XLA hoists it out
+Q8_SHAPES = (("k_v", 3584, 512, 28, (8, 1024)),
+             ("q_o", 3584, 3584, 28, (8, 40, 1024)),
+             ("gate_up", 3584, 18944, 28, (8, 40, 1024)),
+             ("down", 18944, 3584, 28, (8, 40, 1024)),
+             ("lm_head", 3584, 152064, 2, (8, 40)))
+# (rows, route, (bm, bk, bn)); None picks `q8_tiles`, ("K", n) the whole K
+# and the widest block of at most n columns. "sliced" hands the kernel a
+# slice of the stack, as the layer scans did before the kernel read the
+# stack itself, at the 2-D kernel's tiles
+Q8_SWEEP = (
+    (8, "sliced", (8, 512, 256)),
+    (8, "stacked", None),
+    (8, "stacked", ("K", 256)),
+    (40, "stacked", None),
+    (1024, "sliced", (128, 512, 256)),
+    (1024, "stacked", (512, 512, 512)),
+    (1024, "stacked", None),
+)
+
+
+def q8_chip_sweep(out_path, repeats: int = 10):
+    """Time the compiled Q8 kernel on the chip: every published shape under
+    every `Q8_SWEEP` setting, inside a loop over the stack's layers as a
+    layer scan calls it. Reports the mean time a call, the weight-stream
+    rate and the share of the v5e roofline (819 GB/s, 197 TFLOP/s bf16)."""
+    import json
+    from repro.kernels.quant_matmul.quant_matmul import _fit, q8_matmul
+
+    dev = jax.devices()[0]
+    if dev.platform != "tpu":
+        raise SystemExit(f"--chip needs a TPU; JAX sees {dev.platform!r}")
+    rows = []
+    for name, K, N, L, row_counts in Q8_SHAPES:
+        kw, ks, kx = jax.random.split(jax.random.PRNGKey(K * 7 + N), 3)
+        w = jax.jit(lambda k: jax.random.randint(
+            k, (L, K, N), -127, 128, jnp.int8))(kw)
+        scale = jax.random.uniform(ks, (L, 1, N), jnp.float32) * 1e-2
+        for M, route, tiles in Q8_SWEEP:
+            if M not in row_counts:
+                continue
+            if tiles is not None and tiles[0] == "K":
+                tiles = (M, K, _fit(N, tiles[1]))
+            x = jax.random.normal(kx, (M, K), jnp.bfloat16)
+
+            # the weights are arguments: closed over, they would be
+            # compiled into the program as constants
+            @jax.jit
+            def loop(x, w, scale, route=route, tiles=tiles):
+                def body(i, tot):
+                    l = i % L
+                    if route == "sliced":
+                        out = q8_matmul(x, w[l][None], scale[l][None], 0,
+                                        tiles=tiles)
+                    else:
+                        out = q8_matmul(x, w, scale, l, tiles=tiles)
+                    return tot + out[0, 0].astype(jnp.float32)
+                return jax.lax.fori_loop(0, L * repeats, body, 0.0)
+
+            label = f"kernels/q8/{name}_{M}x{K}x{N}/{route}/{tiles or 'auto'}"
+            try:
+                jax.block_until_ready(loop(x, w, scale))
+            except Exception as e:                # a setting the chip refuses
+                rows.append({"label": label, "error": str(e)[:300]})
+                print(f"{label},refused,{str(e)[:120]!r}", flush=True)
+                continue
+            t0 = time.perf_counter()  # cc-lint: disable=CC001 -- real wall-clock is the measurement here
+            for _ in range(3):
+                jax.block_until_ready(loop(x, w, scale))
+            us = (time.perf_counter() - t0) / (3 * L * repeats) * 1e6  # cc-lint: disable=CC001 -- real wall-clock is the measurement here
+            nbytes = K * N + 4 * N + 2 * M * K + 2 * M * N
+            flops = 2 * M * K * N
+            least = max(nbytes / TPU_V5E.hbm_bandwidth,
+                        flops / TPU_V5E.peak_flops)
+            row = {"label": label, "us": us, "gb_s": nbytes / us / 1e3,
+                   "roofline_pct": 100 * least / (us * 1e-6)}
+            rows.append(row)
+            emit(label, us, f"gb_s={row['gb_s']:.1f} "
+                            f"roofline={row['roofline_pct']:.1f}%")
+        del w
+    out_path.parent.mkdir(parents=True, exist_ok=True)
+    out_path.write_text(json.dumps({"device": dev.device_kind, "rows": rows},
+                                   indent=1))
+    return rows
+
+
 if __name__ == "__main__":
-    run()
+    import argparse
+    from pathlib import Path
+    ap = argparse.ArgumentParser(description=__doc__.split("\n")[0])
+    ap.add_argument("--chip", metavar="OUT",
+                    help="time the compiled Q8 kernel on a TPU and write "
+                         "its rows as JSON to OUT")
+    args = ap.parse_args()
+    if args.chip:
+        q8_chip_sweep(Path(args.chip))
+    else:
+        run()

@@ -18,6 +18,8 @@ rows of its first batched paged decode steps are compared with the same model
 on the XLA reference path (`use_pallas=False`) on the same chip, fed the
 engine's tokens. Planted faults (a misread quant scale, a dropped KV head, a
 masked KV block) must read above the tolerance, or the check fails too.
+Every Q8 kernel the engine lowered must read its layer's weights in place
+from the stacked arrays (`[routes]`: 0 sliced).
 
 `--chips 4`: only the sharded path — a dense-layout engine over a 4-device
 data mesh at published widths (Q8), against the same requests on a
@@ -231,6 +233,16 @@ def record_decode_logits(engine, steps: int):
     return rows, live
 
 
+def check_q8_routes():
+    """Every Q8 kernel lowering so far read its weight in place from the
+    layer stack: none was handed a slice for XLA to copy."""
+    from repro.kernels.quant_matmul.ops import Q8_ROUTES
+    log(f"[routes] q8 kernel lowerings: stacked {Q8_ROUTES['stacked']}, "
+        f"sliced {Q8_ROUTES['sliced']}")
+    check(Q8_ROUTES["stacked"] > 0 and Q8_ROUTES["sliced"] == 0,
+          "a Q8 kernel was handed a sliced layer, or none ran")
+
+
 def rel_err(got, want) -> float:
     import numpy as np
     scale = np.abs(want).max(axis=-1)
@@ -294,6 +306,7 @@ def one_chip(cfg, rcfg, phases):
     check(engine.swap_count >= 1, "no live variant swap happened")
     check(engine.kernel_fallbacks == 0,
           f"{engine.kernel_fallbacks} decode steps fell back to the reference")
+    check_q8_routes()
     check(engine.prefix_cache.hits >= len(waves[1][1]),
           "the second wave did not hit the cached tool prefix")
 
@@ -392,6 +405,7 @@ def four_chips(cfg, rcfg, phases):
             check(rows == {econfig.max_batch // 4},
                   f"cache batch rows per device {rows}, expected "
                   f"{econfig.max_batch // 4}")
+    check_q8_routes()
     same = sum(a == b for a, b in zip(outs["sharded"], outs["one-device"]))
     log(f"[mesh] temperature-0 streams identical: {same}/{len(prompts)}")
     first = np.asarray(outs["sharded"])[:, :4].tolist()

@@ -24,6 +24,7 @@ from repro.models import layers as L
 from repro.models import blocks as B_
 from repro.models.moe import moe_spec, moe_apply
 from repro.quant import dense
+from repro.quant.qtensor import layer_inputs, layer_params, one_layer
 from repro.sharding.param import ParamDef
 from repro.sharding.rules import constrain
 
@@ -107,7 +108,8 @@ def unembed(params, h, cfg: ModelConfig, rcfg):
             (((h.ndim - 1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)
     else:
-        logits = dense(h, params["lm_head"], rcfg).astype(jnp.float32)
+        logits = dense(h, one_layer(params["lm_head"]),
+                       rcfg).astype(jnp.float32)
     return L.softcap(logits, cfg.final_logit_softcap)
 
 
@@ -245,10 +247,12 @@ def forward(params, batch, cfg: ModelConfig, rcfg: RuntimeConfig, *,
     cos, sin = rope_for(cfg, batch.get("positions"), Bb, S)
     gs = _pattern(cfg)
     groups = cfg.num_layers // gs
-    layer_params = _group_tree(params["layers"], groups, gs)
+    layers = params["layers"]
+    xs = (jnp.arange(groups), _group_tree(layer_inputs(layers), groups, gs))
 
-    def body_moe_aware(carry, p_g):
+    def body_moe_aware(carry, xs_g):
         x, aux = carry
+        g, p_g = xs_g
         # SP constraint on the block INPUT as well as its output: without it
         # the backward cotangent of the residual enters the layer transpose
         # replicated and every dgrad partial resolves with a full (B,S,d)
@@ -257,7 +261,8 @@ def forward(params, batch, cfg: ModelConfig, rcfg: RuntimeConfig, *,
         x = constrain(x, ("act_batch", "act_seq", "act_embed"))
         kvs = []
         for m in range(gs):
-            p_i = jax.tree.map(lambda a: a[m], p_g)
+            p_i = layer_params(layers, jax.tree.map(lambda a: a[m], p_g),
+                               g * gs + m)
             n = p_i["norms"]
             h = L.rms_norm(x, n["pre_attn"], cfg.norm_eps)
             a, kv = B_.attn_apply(p_i["attn"], h, cfg, rcfg, cos=cos, sin=sin,
@@ -289,15 +294,14 @@ def forward(params, batch, cfg: ModelConfig, rcfg: RuntimeConfig, *,
 
     if rcfg.scan_layers:
         (x, aux), kv_stack = jax.lax.scan(
-            scan_body, (x, jnp.zeros((), jnp.float32)), layer_params)
+            scan_body, (x, jnp.zeros((), jnp.float32)), xs)
     else:
         # unrolled (HLO grows with depth): used by the analytic-flops
         # validation tests, where scan would hide per-layer cost
         carry = (x, jnp.zeros((), jnp.float32))
         kvs = []
         for g in range(groups):
-            p_g = jax.tree.map(lambda a: a[g], layer_params)
-            carry, kv = scan_body(carry, p_g)
+            carry, kv = scan_body(carry, jax.tree.map(lambda a: a[g], xs))
             kvs.append(kv)
         x, aux = carry
         kv_stack = (jax.tree.map(lambda *xs: jnp.stack(xs), *kvs)
@@ -349,8 +353,11 @@ def _prefill_window(params, batch, prefix_k, prefix_v, prefix_lens,
     cos, sin = rope_for(cfg, q_pos if q_pos.ndim == 2 else q_pos[None, :],
                         Bb, S)
 
+    layers = params["layers"]
+
     def body(x, xs):
-        p_i, k_pre, v_pre = xs
+        i, p_i, k_pre, v_pre = xs
+        p_i = layer_params(layers, p_i, i)
         x = constrain(x, ("act_batch", "act_seq", "act_embed"))
         n = p_i["norms"]
         h = L.rms_norm(x, n["pre_attn"], cfg.norm_eps)
@@ -372,8 +379,9 @@ def _prefill_window(params, batch, prefix_k, prefix_v, prefix_lens,
         x = x + m
         return x, (k, v)
 
-    x, (k_suf, v_suf) = jax.lax.scan(body, x,
-                                     (params["layers"], prefix_k, prefix_v))
+    x, (k_suf, v_suf) = jax.lax.scan(
+        body, x, (jnp.arange(cfg.num_layers), layer_inputs(layers),
+                  prefix_k, prefix_v))
     if not need_logits:
         return None, (k_suf, v_suf)
     x = L.rms_norm(x, params["final_norm"], cfg.norm_eps)
@@ -450,8 +458,11 @@ def decode_step_paged(params, pool, tokens, lengths, block_tables,
     Bb = x.shape[0]
     cos, sin = rope_for(cfg, lengths[:, None], Bb, 1)
 
+    layers = params["layers"]
+
     def body(x, xs):
-        p_i, c_i = xs
+        i, p_i, c_i = xs
+        p_i = layer_params(layers, p_i, i)
         n = p_i["norms"]
         h = L.rms_norm(x, n["pre_attn"], cfg.norm_eps)
         a, c_i2 = B_.attn_decode_paged_apply(
@@ -471,7 +482,8 @@ def decode_step_paged(params, pool, tokens, lengths, block_tables,
         x = x + m
         return x, c_i2
 
-    x, new_pool = jax.lax.scan(body, x, (params["layers"], pool))
+    x, new_pool = jax.lax.scan(
+        body, x, (jnp.arange(cfg.num_layers), layer_inputs(layers), pool))
     x = L.rms_norm(x, params["final_norm"], cfg.norm_eps)
     logits = unembed(params, x, cfg, rcfg)[:, 0]
     return logits, new_pool
@@ -489,14 +501,15 @@ def decode_step(params, cache, tokens, lengths, cfg: ModelConfig,
     cos, sin = rope_for(cfg, pos, Bb, 1)
     gs = _pattern(cfg)
     groups = cfg.num_layers // gs
-    layer_params = _group_tree(params["layers"], groups, gs)
+    layers = params["layers"]
     cache_g = _group_tree(cache, groups, gs)
 
     def body(x, xs):
-        p_g, c_g = xs
+        g, p_g, c_g = xs
         new_c = []
         for m in range(gs):
-            p_i = jax.tree.map(lambda a: a[m], p_g)
+            p_i = layer_params(layers, jax.tree.map(lambda a: a[m], p_g),
+                               g * gs + m)
             c_i = jax.tree.map(lambda a: a[m], c_g)
             x, c_i2 = _layer_decode(p_i, x, c_i, lengths, cfg, rcfg, cos, sin,
                                     window_for(cfg, m))
@@ -504,7 +517,9 @@ def decode_step(params, cache, tokens, lengths, cfg: ModelConfig,
         stacked = jax.tree.map(lambda *xs_: jnp.stack(xs_), *new_c)
         return x, stacked
 
-    x, new_cache = jax.lax.scan(body, x, (layer_params, cache_g))
+    x, new_cache = jax.lax.scan(
+        body, x, (jnp.arange(groups),
+                  _group_tree(layer_inputs(layers), groups, gs), cache_g))
     new_cache = jax.tree.map(
         lambda a: a.reshape(a.shape[0] * a.shape[1], *a.shape[2:]), new_cache)
     x = L.rms_norm(x, params["final_norm"], cfg.norm_eps)

@@ -33,14 +33,18 @@ class QTensor:
     zero: Optional[jax.Array]   # q4 only: group minimum, same shape as scale
     fmt: str = "q8"
     group: int = Q4_GROUP
+    # set on a stack of layers (leading dim of q, scale, zero): the traced
+    # index of the layer this tensor stands for (`layer_params`)
+    layer: Optional[jax.Array] = None
 
     def tree_flatten(self):
-        return (self.q, self.scale, self.zero), (self.fmt, self.group)
+        return (self.q, self.scale, self.zero, self.layer), (self.fmt, self.group)
 
     @classmethod
     def tree_unflatten(cls, aux, children):
-        q, scale, zero = children
-        return cls(q=q, scale=scale, zero=zero, fmt=aux[0], group=aux[1])
+        q, scale, zero, layer = children
+        return cls(q=q, scale=scale, zero=zero, fmt=aux[0], group=aux[1],
+                   layer=layer)
 
     @property
     def shape(self) -> Tuple[int, ...]:
@@ -49,6 +53,15 @@ class QTensor:
         if self.fmt == "q4":
             s[-2] *= 2
         return tuple(s)
+
+    def sliced(self) -> "QTensor":
+        """The layer this tensor stands for, cut out of its stack."""
+        if self.layer is None:
+            return self
+        q, scale, zero = jax.tree.map(lambda a: a[self.layer],
+                                      (self.q, self.scale, self.zero))
+        return dataclasses.replace(self, q=q, scale=scale, zero=zero,
+                                   layer=None)
 
     def nbytes(self) -> int:
         n = self.q.size * jnp.dtype(self.q.dtype).itemsize
@@ -131,13 +144,19 @@ def _q4_matmul_xla(x: jax.Array, t: QTensor):
 
 def dense(x: jax.Array, w, rcfg=None, *, spec: Optional[str] = None):
     """x: (..., d_in) @ w: (..., d_in, d_out) with optional leading batch dims
-    on w that broadcast/batch against x (used by stacked experts)."""
+    on w that broadcast/batch against x (used by stacked experts). A Q8
+    QTensor that carries its layer index goes to the kernel as its whole
+    stack, which the kernel reads in place; every other route cuts the layer
+    out first."""
     if _is_qt(w):
-        if rcfg is not None and rcfg.use_pallas:
-            if w.q.ndim != 2:
+        use_kernel = rcfg is not None and rcfg.use_pallas
+        if not (use_kernel and w.fmt == "q8"):
+            w = w.sliced()
+        if use_kernel:
+            if w.q.ndim - (w.layer is not None) != 2:
                 raise NotImplementedError(
-                    f"quant_matmul kernel takes 2-D weights; got {w.fmt} "
-                    f"{w.shape} (batched experts have no kernel path)")
+                    f"quant_matmul kernel takes one 2-D weight a layer; got "
+                    f"{w.fmt} {w.shape} (batched experts have no kernel path)")
             from repro.kernels.quant_matmul import ops as qm_ops
             return batch_parallel(
                 functools.partial(qm_ops.quant_matmul,
@@ -159,6 +178,31 @@ def dense(x: jax.Array, w, rcfg=None, *, spec: Optional[str] = None):
         x, w.astype(x.dtype),
         (((2,), (1,)), ((0,), (0,))),
         preferred_element_type=x.dtype)
+
+
+def layer_inputs(layers):
+    """What a layer scan slices from the stacked layer tree: every leaf but
+    the QTensors, which `layer_params` hands on whole (None in their place)."""
+    return jax.tree.map(lambda a: None if _is_qt(a) else a, layers,
+                        is_leaf=_is_qt)
+
+
+def layer_params(layers, sliced, i):
+    """Layer `i`'s params: `sliced`, the scan's slice of `layer_inputs(layers)`,
+    with each QTensor of `layers` left stacked and carrying the index `i`."""
+    return jax.tree.map(
+        lambda a, s: dataclasses.replace(a, layer=i) if _is_qt(a) else s,
+        layers, sliced, is_leaf=_is_qt)
+
+
+def one_layer(w):
+    """A QTensor stored as one matrix, as a stack of one at index 0, so the
+    Q8 kernel reads it in place like a layer's weight; arrays pass through."""
+    if not _is_qt(w) or w.layer is not None:
+        return w
+    q, scale, zero = jax.tree.map(lambda a: a[None], (w.q, w.scale, w.zero))
+    return dataclasses.replace(w, q=q, scale=scale, zero=zero,
+                               layer=jnp.zeros((), jnp.int32))
 
 
 # ---------------------------------------------------------------------------

@@ -1,11 +1,18 @@
 """Kernel vs pure-jnp-oracle sweeps (shapes x dtypes), interpret mode."""
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.quant import quantize
+from repro.config import ModelConfig, RuntimeConfig
+from repro.models import get_model
+from repro.quant import dense, quantize, quantize_tree
+from repro.sharding.param import init_params
+from repro.quant.qtensor import one_layer
 from repro.kernels.quant_matmul import ops as qm_ops, ref as qm_ref
+from repro.kernels.quant_matmul.quant_matmul import q8_matmul
 from repro.kernels.flash_attention import ops as fa_ops, ref as fa_ref
 from repro.kernels.paged_attention import ops as pa_ops, ref as pa_ref
 from repro.kernels.ssd import ops as ssd_ops, ref as ssd_ref
@@ -32,6 +39,124 @@ def test_quant_matmul(fmt, shape, xdtype):
     gf = np.asarray(got, np.float32)
     wf = np.asarray(want, np.float32)
     rel = np.max(np.abs(gf - wf)) / max(np.max(np.abs(wf)), 1e-6)
+    assert rel < 0.02, rel
+
+
+@pytest.mark.parametrize("L,K,N", [(4, 256, 384), (1, 384, 1280)])
+@pytest.mark.parametrize("M", [8, 40, 1024])
+@pytest.mark.parametrize("xdtype", [jnp.bfloat16, jnp.float32])
+def test_q8_matmul_stacked(L, K, N, M, xdtype):
+    """The Q8 kernel reading layer l of an (L, K, N) stack in place, at the
+    first, a middle and the last layer, against the oracle on the sliced
+    layer; L = 1 is a weight stored as one matrix (the LM head). Decode rows
+    (8), a verify window (40) and a prefill (1024) take their own tiles."""
+    k1, k2 = jax.random.split(jax.random.fold_in(KEY, L * K + N + M))
+    x = jax.random.normal(k1, (M, K), xdtype)
+    t = quantize(jax.random.normal(k2, (L, K, N), jnp.float32) * 0.05, "q8")
+    for layer in sorted({0, L // 2, L - 1}):
+        got = np.asarray(q8_matmul(x, t.q, t.scale, layer, interpret=True),
+                         np.float32)
+        want = np.asarray(qm_ref.q8_matmul_ref(x, t.q[layer], t.scale[layer]),
+                          np.float32)
+        if xdtype == jnp.float32:
+            np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
+        else:       # the two sum in different orders before rounding to bf16
+            rel = np.max(np.abs(got - want)) / np.max(np.abs(want))
+            assert rel < 0.01, (layer, rel)
+
+
+@pytest.mark.parametrize("route", ["q8 kernel", "q8 xla", "q4 kernel",
+                                   "q4 xla"])
+def test_dense_on_stacked_view_equals_slice(route):
+    """dense() on a stack that carries its layer index equals dense() on the
+    layer cut out of the stack, on every route; the Q8 kernel counts the
+    stacked view as read in place and the lone matrix as sliced."""
+    fmt, path = route.split()
+    L, K, N = 3, 256, 384
+    k1, k2 = jax.random.split(jax.random.fold_in(KEY, 7))
+    x = jax.random.normal(k1, (2, 4, K), jnp.bfloat16)
+    t = quantize(jax.random.normal(k2, (L, K, N), jnp.float32) * 0.05, fmt)
+    rcfg = (RuntimeConfig(use_pallas=True, interpret=True) if path == "kernel"
+            else RuntimeConfig(use_pallas=False))
+    for layer in range(L):
+        stacked = dataclasses.replace(t, layer=jnp.int32(layer))
+        got = dense(x, stacked, rcfg)
+        want = dense(x, stacked.sliced(), rcfg)
+        np.testing.assert_array_equal(np.asarray(got, np.float32),
+                                      np.asarray(want, np.float32))
+    if fmt == "q8" and path == "kernel":
+        qm_ops.quant_matmul.clear_cache()
+        qm_ops.Q8_ROUTES.clear()
+        dense(x, stacked, rcfg)
+        dense(x, one_layer(stacked.sliced()), rcfg)
+        assert qm_ops.Q8_ROUTES == {"stacked": 2}
+        dense(x, stacked.sliced(), rcfg)
+        assert qm_ops.Q8_ROUTES == {"stacked": 2, "sliced": 1}
+
+
+def _greedy_run(model, params, rcfg, prompt, steps, bs=16):
+    """Prefill `prompt` (B, S), prefill a 16-token suffix over it as a cached
+    prefix, then `steps` greedy paged decode steps: every position's logits
+    and the tokens chosen."""
+    B, S = prompt.shape
+    nb = 4
+    cache = init_params(model.cache_spec(rcfg, B, nb * bs), KEY)
+    logits, cache, _ = model.prefill(params, cache, {"tokens": prompt}, rcfg)
+    suffix = jnp.argsort(prompt, axis=1)[:, :16].astype(jnp.int32)
+    suf_logits, (k_suf, v_suf) = model.prefill_paged(
+        params, {"tokens": suffix, "positions": S + jnp.arange(16)},
+        cache["k"][:, :, :S], cache["v"][:, :, :S],
+        jnp.full((B,), S, jnp.int32), rcfg)
+    # row b's blocks are 1 + b * nb ... (block 0 is the reserved scratch)
+    pool = init_params(model.paged_cache_spec(rcfg, B * nb + 1, bs), KEY)
+    full = {"k": jnp.concatenate([cache["k"][:, :, :S], k_suf], axis=2),
+            "v": jnp.concatenate([cache["v"][:, :, :S], v_suf], axis=2)}
+    n = (S + 16) // bs
+    for key, val in full.items():
+        blocks = val.reshape(val.shape[0], B * n, bs, *val.shape[3:])
+        ids = (1 + jnp.arange(B)[:, None] * nb + jnp.arange(n)).reshape(-1)
+        pool[key] = pool[key].at[:, ids].set(blocks.astype(pool[key].dtype))
+    tables = (1 + jnp.arange(B)[:, None] * nb + jnp.arange(nb)).astype(
+        jnp.int32)
+    rows, toks = [logits, suf_logits], []
+    tok = jnp.argmax(suf_logits, axis=-1).astype(jnp.int32)
+    lengths = jnp.full((B,), S + 16, jnp.int32)
+    for _ in range(steps):
+        toks.append(tok)
+        logits, pool = model.decode_step_paged(
+            params, pool, tok[:, None], lengths, tables, rcfg,
+            seq_cap=nb * bs)
+        rows.append(logits)
+        tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        lengths = lengths + 1
+    toks.append(tok)
+    return (np.stack([np.asarray(r, np.float32) for r in rows]),
+            np.stack([np.asarray(t) for t in toks]))
+
+
+def test_stacked_q8_model_greedy_parity():
+    """The model on the Q8 kernel route (each layer's weights read in place
+    from the stack, interpret mode) against the XLA reference route: cold
+    prefill, a suffix prefill over a cached prefix and paged decode steps
+    give the same greedy tokens, and the kernel is handed no sliced layer."""
+    cfg = ModelConfig(name="tiny-q8", family="transformer", num_layers=3,
+                      d_model=128, num_heads=4, num_kv_heads=2, head_dim=32,
+                      d_ff=256, vocab_size=384, qkv_bias=True)
+    model = get_model(cfg)
+    spec = model.param_spec()
+    params = quantize_tree(init_params(spec, KEY), spec, "q8")
+    prompt = jax.random.randint(jax.random.fold_in(KEY, 3), (2, 32), 0,
+                                cfg.vocab_size, jnp.int32)
+    qm_ops.quant_matmul.clear_cache()
+    qm_ops.Q8_ROUTES.clear()
+    got, got_toks = _greedy_run(
+        model, params, RuntimeConfig(use_pallas=True, interpret=True), prompt,
+        steps=4)
+    assert qm_ops.Q8_ROUTES["sliced"] == 0 < qm_ops.Q8_ROUTES["stacked"]
+    want, want_toks = _greedy_run(
+        model, params, RuntimeConfig(use_pallas=False), prompt, steps=4)
+    np.testing.assert_array_equal(got_toks, want_toks)
+    rel = np.max(np.abs(got - want)) / np.max(np.abs(want))
     assert rel < 0.02, rel
 
 

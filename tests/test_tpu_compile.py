@@ -9,6 +9,7 @@ fixture, never at import: only one process may load the TPU library, and
 under pytest-xdist every worker imports this file.
 """
 import os
+import re
 
 import pytest
 
@@ -21,7 +22,7 @@ from repro.kernels.paged_attention.paged_attention import paged_attention_bkgh
 from repro.kernels.quant_matmul.quant_matmul import q4_matmul, q8_matmul
 from repro.kernels.topk_sim.topk_sim import sim_scores
 
-D, FF, VOCAB = 3584, 18944, 152064
+LAYERS, D, FF, VOCAB = 28, 3584, 18944, 152064
 N_HEADS, K_HEADS, HEAD = 28, 4, 128
 BLOCK, POOL_BLOCKS, CHAIN = 16, 154, 16       # the smoke run's paged pool
 DECODE_ROWS = 8                               # quant_matmul pads decode to 8
@@ -72,13 +73,69 @@ def test_paged_attention_compiles(one_chip, kv_dtype, splits):
 @pytest.mark.parametrize("n_out", [FF, K_HEADS * HEAD, VOCAB])
 def test_quant_matmul_compiles(one_chip, fmt, n_out):
     x = ((DECODE_ROWS, D), jnp.bfloat16)
-    if fmt == "q8":
-        _compile(lambda x, w, s: q8_matmul(x, w, s, bm=DECODE_ROWS), one_chip,
+    if fmt == "q8":         # a lone matrix is a stack of one
+        _compile(lambda x, w, s: q8_matmul(x, w[None], s[None], 0), one_chip,
                  x, ((D, n_out), jnp.int8), ((1, n_out), jnp.float32))
     else:
         groups = ((D // 128, n_out), jnp.float32)
         _compile(lambda x, w, s, z: q4_matmul(x, w, s, z, bm=DECODE_ROWS),
                  one_chip, x, ((D // 2, n_out), jnp.uint8), groups, groups)
+
+
+@pytest.mark.parametrize("k_in,n_out,layers", [
+    (D, K_HEADS * HEAD, LAYERS), (D, D, LAYERS), (D, FF, LAYERS),
+    (FF, D, LAYERS), (D, VOCAB, 1)])
+def test_stacked_q8_matmul_compiles(one_chip, k_in, n_out, layers):
+    """Every published decode product reading its layer of the stack in
+    place, at the tiles `q8_tiles` picks, within the default scoped VMEM;
+    the LM head is a stack of one."""
+    _compile(lambda x, w, s, i: q8_matmul(x, w, s, i), one_chip,
+             ((DECODE_ROWS, k_in), jnp.bfloat16),
+             ((layers, k_in, n_out), jnp.int8),
+             ((layers, 1, n_out), jnp.float32), ((), jnp.int32))
+
+
+def test_paged_decode_reads_q8_weights_in_place(one_chip):
+    """The engine's paged decode step at published widths: no int8
+    dynamic-slice (a per-layer weight copy) feeds a `quant_matmul` call, and
+    every call's operands start (bf16 x, s8 weights, f32 scales), the order
+    the benchmark's Q8 roofline reads."""
+    from repro.common.registry import get_arch
+    from repro.config import RuntimeConfig
+    from repro.models import get_model
+    from repro.quant import quant_spec
+    from repro.serving.engine import _EngineExec
+    from repro.sharding.param import abstract_params
+
+    jax.config.update("jax_enable_compilation_cache", False)
+    model = get_model(get_arch("carboncall-qwen2-7b"))
+    rcfg = RuntimeConfig(use_pallas=True, interpret=False)
+
+    def on_chip(spec):
+        return jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+            abstract_params(spec))
+    params = on_chip(quant_spec(model.param_spec(), "q8"))
+    pool = on_chip(model.paged_cache_spec(rcfg, POOL_BLOCKS, BLOCK))
+    i32 = [jax.ShapeDtypeStruct(s, jnp.int32, sharding=one_chip)
+           for s in ((DECODE_ROWS, 1), (DECODE_ROWS,), (DECODE_ROWS, CHAIN))]
+    ex = _EngineExec(model, rcfg, CHAIN * BLOCK, block_size=BLOCK)
+    hlo = jax.jit(ex.decode_paged_impl, donate_argnums=(1,)).lower(
+        params, pool, *i32).compile().as_text()
+
+    defs = dict(re.findall(r"^\s*(?:ROOT )?(%[\w.-]+) = (.*)$", hlo, re.M))
+    calls = {n: d for n, d in defs.items()
+             if n.startswith("%quant_matmul") and " custom-call(" in d}
+    assert len(calls) == 8      # q, k, v, o, gate, up, down; the LM head
+    for name, d in calls.items():
+        layout = re.search(r"operand_layout_constraints=\{(.*?)\}, [a-z_]+=", d)
+        assert re.findall(r"(\w+)\[", layout.group(1))[:3] == [
+            "bf16", "s8", "f32"], (name, layout.group(1))
+        args = re.search(r" custom-call\((.*?)\)", d).group(1)
+        for op in re.findall(r"%[\w.-]+", args):
+            src = defs.get(op, "")
+            assert not (src.startswith("s8[") and "dynamic-slice" in
+                        op + src), (name, op, src[:200])
 
 
 def test_flash_attention_compiles(one_chip):
