@@ -30,12 +30,14 @@ def main() -> int:
                     help="comma-separated prompt buckets (default: all)")
     args = ap.parse_args()
 
+    import json
+
     import jax
     import jax.numpy as jnp
     from jax.experimental import topologies
     from jax.sharding import SingleDeviceSharding
 
-    from bench import harness, reference, weights
+    from bench import harness, weights
     from repro.config import RuntimeConfig
     from repro.models import get_model
     from repro.serving.engine import _EngineExec
@@ -45,11 +47,10 @@ def main() -> int:
     topo = topologies.get_topology_desc(platform="tpu",
                                         topology_name="v5e:2x2")
     chip = SingleDeviceSharding(topo.devices[0])
-    cell = harness.Cell(args.config, 1,
-                        __import__("json").loads(
-                            (harness.BENCH / "configs" / f"{args.config}.json")
-                            .read_text()), {"variant": args.variant}, {})
-    dims = weights.dims_of(cell.cfg)
+    cfg = json.loads((harness.BENCH / "configs" / f"{args.config}.json")
+                     .read_text())
+    arch = harness.load_arch(cfg)
+    dims = arch.dims_of(cfg)
 
     def on_chip(tree):
         return jax.tree.map(
@@ -64,23 +65,19 @@ def main() -> int:
               f"{m.temp_size_in_bytes} B", flush=True)
         return c
 
-    items = tuple(sorted((k, v) for k, v in dims.items()
-                         if k in ("L", "d", "f", "V", "N", "K", "H")))
-    fmts = tuple(cell.cfg["variants"])
-    key = on_chip(jax.eval_shape(lambda: jax.random.key(0)))
-    report("weights.make", weights._make.lower(items, fmts, key))
-    w = on_chip(jax.eval_shape(lambda k: weights._make(items, fmts, k), key))
+    fmts = tuple(cfg["variants"])
+    key = on_chip(jax.eval_shape(lambda: weights.seed_key(0)))
 
-    import dataclasses
-    from repro.common.registry import get_arch
-    mc = dataclasses.replace(
-        get_arch(cell.cfg["arch"]), num_layers=dims["L"], d_model=dims["d"],
-        d_ff=dims["f"], vocab_size=dims["V"], num_heads=dims["N"],
-        num_kv_heads=dims["K"], head_dim=dims["H"])
-    model = get_model(mc)
-    params = weights.program_params(w, args.variant, model.param_spec())
+    def draw(k):
+        return arch.draw(dims, fmts, k)
+
+    report("weights.draw", jax.jit(draw).lower(key))
+    w = on_chip(jax.eval_shape(draw, key))
+
+    model = get_model(arch.model_config(cfg, dims))
+    params = arch.program_params(w, args.variant, model.param_spec())
     rcfg = RuntimeConfig(use_pallas=True, interpret=False)
-    eng = cell.cfg["engine"]
+    eng = cfg["engine"]
     bs, B, max_seq = eng["block_size"], eng["max_batch"], eng["max_seq"]
     nb = -(-max_seq // bs)
     num_blocks = (B + 1) * nb + B + 2
@@ -107,21 +104,8 @@ def main() -> int:
         params, pool, jax.ShapeDtypeStruct((B, 1), i32, sharding=chip),
         jax.ShapeDtypeStruct((B,), i32, sharding=chip),
         jax.ShapeDtypeStruct((B, nb), i32, sharding=chip)))
-    ritems = tuple(sorted(dims.items()))
-    lw = reference.layer_weights(w, args.variant)
-    x = jax.ShapeDtypeStruct((8, 1024, dims["d"]), jnp.float32, sharding=chip)
-    report("reference layer (8, 1024)", reference._layer.lower(
-        ritems, args.variant, None, x, lw,
-        jax.ShapeDtypeStruct((), i32, sharding=chip)))
-    xs = jax.ShapeDtypeStruct((1024, dims["d"]), jnp.float32, sharding=chip)
-    V = dims["V"]
-    cols = V // next(p for p in range(1, V + 1) if V % p == 0
-                     and V // p <= 40000)
-    report(f"reference head (1024 positions, {cols} columns)",
-           reference._head.lower(
-               ritems, args.variant, None, cols, xs, w["final_norm"],
-               w["lm_head"][args.variant],
-               jax.ShapeDtypeStruct((), i32, sharding=chip)))
+    for name, lowered in arch.aot_reference(w, args.variant, dims, chip):
+        report(name, lowered)
     return 0
 
 

@@ -73,15 +73,15 @@ def main() -> int:
                "checked_requests": len(pairs),
                "checked_tokens": sum(len(o) for _, o in pairs),
                "program": correct.logit_gaps(
-                   served.w, cell.variant, served.dims, pairs,
+                   cell.arch, served.w, cell.variant, served.dims, pairs,
                    **served.check_shape())}
         row["program_correct"] = correct.passed(
             correct.checks(win, row["program"], limits))
         if seed in ctrl_seeds:
             for act in controls:
                 gaps = correct.control_gaps(
-                    served.w, cell.variant, served.dims, pairs, act,
-                    **served.check_shape())
+                    cell.arch, served.w, cell.variant, served.dims, pairs,
+                    act, **served.check_shape())
                 row[f"control_{act}"] = gaps
                 row[f"control_{act}_correct"] = correct.passed(
                     correct.checks(win, gaps, limits))

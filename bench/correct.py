@@ -1,11 +1,12 @@
 """Whether what the timed path served is correct.
 
 Once the window has closed and the program's state is freed, the plain
-reference (`bench/reference.py`) reads each sampled request's prompt and
-served tokens in one teacher-forced pass. At every served position it
-measures how far the served token's reference logit lies below the
-reference's best logit there. The numbers compared are the widest such gap
-over the sample (`max_logit_gap`) and their mean (`mean_logit_gap`), each
+reference of the configuration's architecture (`logits_at` of its plug-in,
+`bench/archs/<name>.py`, built on `bench/reference.py`) reads each sampled
+request's prompt and served tokens in one teacher-forced pass. At every
+served position it measures how far the served token's reference logit lies
+below the reference's best logit there. The numbers compared are the widest
+such gap over the sample (`max_logit_gap`) and their mean (`mean_logit_gap`), each
 where the cell's `bench/limits/<cell>.json` gives it a limit: greedy
 serving at temperature 0 should pick the reference's best token, or one
 within bf16 rounding of it.
@@ -40,20 +41,21 @@ def _numbers(gaps) -> Dict[str, float]:
             "mean_logit_gap": float(gaps.mean())}
 
 
-def logit_gaps(w, fmt, dims, pairs, **shape) -> Dict[str, float]:
-    """The widest and the mean gap of the served tokens. `shape`:
-    `seq_len` and `positions` for `reference.logits_at`."""
-    logits, tokens = reference.logits_at(w, fmt, dims, pairs, **shape)
+def logit_gaps(arch, w, fmt, dims, pairs, **shape) -> Dict[str, float]:
+    """The widest and the mean gap of the served tokens. `arch`: the
+    architecture plug-in; `shape`: `seq_len` and `positions` for its
+    `logits_at`."""
+    logits, tokens = arch.logits_at(w, fmt, dims, pairs, **shape)
     return _numbers(reference.gaps(logits, tokens))
 
 
-def control_gaps(w, fmt, dims, pairs, act: str = "fp8",
+def control_gaps(arch, w, fmt, dims, pairs, act: str = "fp8",
                  **shape) -> Dict[str, float]:
     """The same numbers for the tokens a lower-precision pass (`act`
     activations) puts first, at the same positions of the same prompts and
     served tokens."""
-    ref, _ = reference.logits_at(w, fmt, dims, pairs, **shape)
-    low, _ = reference.logits_at(w, fmt, dims, pairs, act=act, **shape)
+    ref, _ = arch.logits_at(w, fmt, dims, pairs, **shape)
+    low, _ = arch.logits_at(w, fmt, dims, pairs, act=act, **shape)
     return _numbers(reference.gaps(ref, low.argmax(axis=-1)))
 
 

@@ -1,6 +1,7 @@
-"""What every benchmark entry point shares: finding a cell's files, the
-compile cache, building the served model from the seed, warming it up, the
-open-loop window, and the sample the correctness check reads.
+"""What every benchmark entry point shares: finding a cell's files and its
+architecture's plug-in, the compile cache, building the served model from the
+seed, warming it up, the open-loop window, and the sample the correctness
+check reads.
 
 The system under test is `repro.serving.ServingEngine`, driven through
 `EngineClient.submit` and `ServingEngine.step()` in this process. Nothing
@@ -11,6 +12,7 @@ from __future__ import annotations
 
 import dataclasses
 import gc
+import importlib.util
 import json
 import sys
 import time
@@ -19,6 +21,7 @@ from typing import Dict, List, Optional
 
 ROOT = Path(__file__).resolve().parents[1]
 BENCH = ROOT / "bench"
+ARCHS = BENCH / "archs"
 # a fixed path inside the checkout: the path is part of the cache's key
 CACHE_DIR = BENCH / ".jax_cache"
 for _p in (str(ROOT), str(ROOT / "src")):
@@ -30,9 +33,8 @@ import numpy as np  # noqa: E402
 from bench import traffic as traffic_mod  # noqa: E402
 from bench import weights as weights_mod  # noqa: E402
 
-# rehearsal on a CPU: every width and length cut, kernels in interpret mode
-REHEARSE_DIMS = {"L": 2, "d": 256, "f": 512, "N": 4, "K": 2, "H": 64,
-                 "V": 1024}
+# rehearsal on a CPU: every length cut by this, every width to the
+# architecture's REHEARSE_DIMS, kernels in interpret mode
 REHEARSE_SCALE = 1 / 8
 
 
@@ -63,9 +65,38 @@ class Cell:
     def variant(self) -> str:
         return self.mix["variant"]
 
+    @property
+    def arch(self):
+        """The configuration's architecture plug-in (`load_arch`)."""
+        return load_arch(self.cfg)
+
     def limits(self) -> dict:
         """bench/limits/<cell>.json: each compared number's limit."""
         return json.loads((BENCH / "limits" / f"{self.name}.json").read_text())
+
+
+def load_arch(cfg: dict):
+    """`bench/archs/<name>.py` for the configuration's `"bench_arch": <name>`,
+    loaded by its path once a process, so that its jitted programs are
+    shared by every caller. It holds all the benchmark knows of the
+    architecture; `bench/archs/qwen2.py` lists what it provides."""
+    name = cfg.get("bench_arch")
+    if not name:
+        raise KeyError(
+            f"configuration {cfg.get('arch', '?')!r} names no architecture "
+            "plug-in: give its file \"bench_arch\": \"<name>\" for "
+            f"{ARCHS.relative_to(ROOT)}/<name>.py")
+    key = f"bench_arch_{name}"
+    if key not in sys.modules:
+        path = ARCHS / f"{name}.py"
+        if not path.is_file():
+            raise FileNotFoundError(f"no architecture plug-in {path} for "
+                                    f"\"bench_arch\": {name!r}")
+        spec = importlib.util.spec_from_file_location(key, path)
+        module = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(module)
+        sys.modules[key] = module
+    return sys.modules[key]
 
 
 def load_spec() -> dict:
@@ -96,25 +127,19 @@ class Served:
 
     def __init__(self, cell: Cell, seed: int, *, rehearse: bool = False):
         import jax
-        from repro.common.registry import get_arch
         from repro.config import RuntimeConfig
         from repro.models import get_model
         from repro.serving import EngineConfig
 
         self.cell = cell
         self.rehearse = rehearse
+        arch = cell.arch
         cfg = cell.cfg
-        dims = weights_mod.dims_of(cfg)
+        dims = arch.dims_of(cfg)
         if rehearse:
-            dims.update(REHEARSE_DIMS)
+            dims.update(arch.REHEARSE_DIMS)
         self.dims = dims
-        self.model_cfg = dataclasses.replace(
-            get_arch(cfg["arch"]), num_layers=dims["L"], d_model=dims["d"],
-            d_ff=dims["f"], vocab_size=dims["V"], num_heads=dims["N"],
-            num_kv_heads=dims["K"], head_dim=dims["H"],
-            rope_theta=float(cfg["rope_theta"]), norm_eps=float(cfg["rms_norm_eps"]),
-            qkv_bias=bool(cfg["assumed"]["qkv_bias"]),
-            tie_embeddings=bool(cfg["tie_word_embeddings"]), act_fn="silu")
+        self.model_cfg = arch.model_config(cfg, dims)
         self.rcfg = (RuntimeConfig(use_pallas=True, interpret=True)
                      if rehearse else RuntimeConfig())
         e = dict(cfg["engine"])
@@ -125,10 +150,11 @@ class Served:
             kv_layout=e["kv_layout"], kv_cache_dtype=e["kv_cache_dtype"],
             block_size=e["block_size"], variants=tuple(cfg["variants"]))
         self.scale = scale
-        self.w = weights_mod.make(dims, seed, tuple(cfg["variants"]))
+        self.w = arch.draw(dims, tuple(cfg["variants"]),
+                           weights_mod.seed_key(seed))
         jax.block_until_ready(self.w)
         spec = get_model(self.model_cfg).param_spec()
-        self.params = {f: weights_mod.program_params(self.w, f, spec)
+        self.params = {f: arch.program_params(self.w, f, spec)
                        for f in cfg["variants"]}
 
     def engine(self):

@@ -4,7 +4,8 @@
     python3 bench/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1>
 
 A cell (an entry of `workloads` in BENCHMARK.json) names a configuration
-(`bench/configs/<config>.json`) and a traffic mix (`bench/traffic/<mix>.json`).
+(`bench/configs/<config>.json`, which names its architecture's plug-in,
+`bench/archs/<bench_arch>.py`) and a traffic mix (`bench/traffic/<mix>.json`).
 The run draws the weights from the seed on the device, warms up every shape
 the mix uses (set-up, timed as `setup_s`), then offers the mix's open-loop
 traffic to `ServingEngine` for `--seconds` on the wall clock. After the
@@ -160,8 +161,8 @@ def main() -> int:
     pairs = correct.served_pairs(sample)
     served.free_program()
     t_ref = time.perf_counter()
-    gaps = (correct.logit_gaps(served.w, cell.variant, served.dims, pairs,
-                               **served.check_shape())
+    gaps = (correct.logit_gaps(cell.arch, served.w, cell.variant,
+                               served.dims, pairs, **served.check_shape())
             if pairs else {"max_logit_gap": float("inf"),
                            "mean_logit_gap": float("inf")})
     log(f"run: reference over {len(pairs)} requests, "

@@ -7,17 +7,10 @@ reduced widths (d 512, 4 layers, vocabulary 4096) and the program runs its
 XLA path. Everything else is a run's: the open-loop window over the short
 mix (16 requests/s for 3 s, so that every decode slot stays full), the
 seeded sample, the reference and the checks. At this size, over seeds 1-5,
-the program's widest gap read 0.0045-0.0193 and the fp8 control's
-0.137-0.297 (the int8 control's 0.046-0.079 does not separate here), so the
-test limit is 0.06. Each fault planted in the timed path must turn
-`correct` false.
-
-The CPU backend runs with synchronous dispatch here. The engine hands its
-host `lengths` and block tables to `jnp.asarray` and then updates them in
-place; on the CPU a numpy array can be used without a copy, so with
-asynchronous dispatch the step may read the updated values, and under this
-load it serves wrong tokens (widest gaps 0.4-0.8 on seeds 2-5). PERF.md
-lists this under Open questions.
+the program's widest gap read 0.0099-0.0411 (the same with the CPU's
+dispatch synchronous or asynchronous) and the fp8 control's 0.137-0.297
+(the int8 control's 0.046-0.079 does not separate here), so the test limit
+is 0.06. Each fault planted in the timed path must turn `correct` false.
 """
 import functools
 
@@ -32,12 +25,8 @@ SECONDS = 3.0
 
 @pytest.fixture(autouse=True)
 def small(monkeypatch):
-    import jax
-    monkeypatch.setattr(harness, "REHEARSE_DIMS", DIMS)
-    was = jax.config.read("jax_cpu_enable_async_dispatch")
-    jax.config.update("jax_cpu_enable_async_dispatch", False)
-    yield
-    jax.config.update("jax_cpu_enable_async_dispatch", was)
+    monkeypatch.setattr(harness.load_cell("qwen2-7b.short128-q8").arch,
+                        "REHEARSE_DIMS", DIMS)
 
 
 def serve(seed, plant=None):
@@ -59,8 +48,8 @@ def serve(seed, plant=None):
 
 
 def verdict(served, win, pairs):
-    gaps = correct.logit_gaps(served.w, served.cell.variant, served.dims,
-                              pairs, **served.check_shape())
+    gaps = correct.logit_gaps(served.cell.arch, served.w, served.cell.variant,
+                              served.dims, pairs, **served.check_shape())
     return correct.passed(correct.checks(win, gaps, LIMIT)), gaps
 
 
@@ -68,8 +57,9 @@ def test_program_is_correct_and_control_is_not():
     served, win, pairs = serve(5)
     ok, gap = verdict(served, win, pairs)
     assert ok, gap
-    ctrl = correct.control_gaps(served.w, served.cell.variant, served.dims,
-                                pairs, "fp8", **served.check_shape())
+    ctrl = correct.control_gaps(served.cell.arch, served.w, served.cell.variant,
+                                served.dims, pairs, "fp8",
+                                **served.check_shape())
     assert ctrl["max_logit_gap"] > LIMIT["max_logit_gap"], ctrl
 
 

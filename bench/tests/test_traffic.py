@@ -96,8 +96,8 @@ def test_warm_up_leaves_nothing_to_compile(mix, monkeypatch):
     program's XLA path: after the warm-up, a window that keeps every decode
     slot full and its drain compile nothing."""
     from repro.config import RuntimeConfig
-    monkeypatch.setattr(harness, "REHEARSE_DIMS", SMALL)
     cell = harness.load_cell("qwen2-7b.short128-q8")
+    monkeypatch.setattr(cell.arch, "REHEARSE_DIMS", SMALL)
     cell.mix = dict(mix, rate_per_s=16.0)
     served = harness.Served(cell, 3, rehearse=True)
     served.rcfg = RuntimeConfig(use_pallas=False)
